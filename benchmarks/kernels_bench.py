@@ -1,5 +1,6 @@
-"""Kernel micro-bench: us_per_call of each Pallas kernel (interpret mode —
-CPU wall times are NOT TPU times; the roofline in benchmarks/roofline.py is
+"""Kernel micro-bench: us_per_call of each Pallas kernel (the platform picks
+the mode, so on CPU this times the Pallas interpreter — CPU wall times are
+NOT TPU times; the roofline in benchmarks/roofline.py is
 the performance source of truth. This bench proves the kernels execute and
 tracks relative regressions)."""
 from __future__ import annotations

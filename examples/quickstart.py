@@ -32,7 +32,7 @@ def fresh_scheduler(scheme="hier", seed=0, max_workers=200):
 cfg = reduced(ARCHS["olmo-1b"])
 print(f"[1/3] training reduced {cfg.arch_id} "
       f"({registry.param_count(cfg)/1e6:.1f}M params)")
-_, losses = train(cfg, steps=40, batch=8, seq=64, strategy="hier",
+_, losses, _ = train(cfg, steps=40, batch=8, seq=64, strategy="hier",
                   lr=1e-3, log_every=20)
 assert losses[-1] < losses[0]
 

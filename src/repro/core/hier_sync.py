@@ -26,17 +26,6 @@ from jax.sharding import PartitionSpec as P
 STRATEGIES = ("allreduce", "hier", "hier2", "hier2_q", "ps")
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new API, ``check_vma``) with a fallback to
-    ``jax.experimental.shard_map`` (``check_rep``) for older jaxlibs."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def _flat_pad(g, n: int):
     flat = g.reshape(-1)
     pad = (-flat.shape[0]) % n
@@ -151,7 +140,7 @@ def make_sync_grad_fn(loss_fn: Callable, mesh: Mesh, strategy: str,
             loss = jax.lax.pmean(loss, pod_axis)
         return loss, grads
 
-    return shard_map_compat(
+    return jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(batch_axes)),
-        out_specs=(P(), P()))
+        out_specs=(P(), P()), check_vma=False)

@@ -15,10 +15,13 @@ keeping the lane dimension at the 128-multiple the VPU wants.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _agg_kernel(shards_ref, out_ref, *, n_workers: int):
@@ -48,7 +51,7 @@ def _grid_and_specs(n_workers: int, length: int, block: int):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def aggregate_shards(shards: jax.Array, *, block: int = 8 * 1024,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: Optional[bool] = None) -> jax.Array:
     """shards: (n_workers, shard_len) -> (shard_len,) mean.
 
     shard_len must be a multiple of ``block`` (ops.py pads).
@@ -61,7 +64,7 @@ def aggregate_shards(shards: jax.Array, *, block: int = 8 * 1024,
         in_specs=[in_spec],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((length,), shards.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(shards)
 
 
@@ -69,7 +72,7 @@ def aggregate_shards(shards: jax.Array, *, block: int = 8 * 1024,
                    static_argnames=("lr", "block", "interpret"))
 def aggregate_and_apply(shards: jax.Array, param_shard: jax.Array, *,
                         lr: float, block: int = 8 * 1024,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """Fused mean-aggregate + SGD apply on the owned shard.
     shards: (n_workers, shard_len); param_shard: (shard_len,)."""
     n, length = shards.shape
@@ -80,5 +83,5 @@ def aggregate_and_apply(shards: jax.Array, param_shard: jax.Array, *,
         in_specs=[in_spec, pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((length,), param_shard.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(shards, param_shard)

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels: pad to block multiples,
-invoke the kernel, slice back. ``interpret`` defaults to True (this
-container is CPU-only; on a real TPU pass interpret=False)."""
+invoke the kernel, slice back. ``interpret=None`` lets the platform pick
+the mode (``repro.kernels.resolve_interpret``)."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,7 @@ def _pad_to(x, axis: int, mult: int):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def aggregate_shards(shards, *, block: int = 8 * 1024,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """(n_workers, L) -> (L,) mean — the paper's shard-aggregator step."""
     n, L = shards.shape
     block = min(block, max(128, L))
@@ -35,7 +36,8 @@ def aggregate_shards(shards, *, block: int = 8 * 1024,
 
 @functools.partial(jax.jit, static_argnames=("lr", "block", "interpret"))
 def aggregate_and_apply(shards, param, *, lr: float,
-                        block: int = 8 * 1024, interpret: bool = True):
+                        block: int = 8 * 1024,
+                        interpret: Optional[bool] = None):
     n, L = shards.shape
     block = min(block, max(128, L))
     x, _ = _pad_to(shards, 1, block)
@@ -83,7 +85,7 @@ _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """(b, h, s, d) attention; pads seq to block multiples. Differentiable:
     Pallas forward + blockwise-jnp backward via custom_vjp."""
     return _flash_diff(q, k, v, causal, window, block_q, block_k, interpret)
@@ -116,7 +118,8 @@ def _flash_pallas(q, k, v, causal, window, block_q, block_k, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
+             interpret: Optional[bool] = None):
     """Mamba2 SSD over (b, s, h, p); pads seq to the chunk multiple."""
     b, s, h, p = x.shape
     chunk = min(chunk, max(16, s))

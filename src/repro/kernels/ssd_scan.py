@@ -15,11 +15,14 @@ score-x product — both 128-aligned.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, final_ref,
@@ -38,15 +41,20 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, final_ref,
     C = c_ref[0].astype(jnp.float32)          # (Q, n)
     D = d_ref[0].astype(jnp.float32)          # (1, 1)
 
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = ii >= jj
+
     dA = dt * A                               # (Q, 1)
-    seg = jnp.cumsum(dA, axis=0)              # (Q, 1)
+    # inclusive prefix sum as a masked row reduction: Mosaic has no cumsum
+    # lowering, and an f32 VPU sum keeps full precision (an MXU matmul
+    # against the mask would round dA to bf16 under default precision)
+    seg = jnp.sum(jnp.where(causal, dA.T, 0.0), axis=1,
+                  keepdims=True)              # (Q, 1)
     xdt = x * dt                              # (Q, p)
 
     # intra-chunk: masked decayed CB^T
     CB = C @ B.T                              # (Q, Q)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    causal = ii >= jj
     diff = jnp.where(causal, seg - seg.T, -jnp.inf)   # seg_i - seg_j
     y = (CB * jnp.exp(diff)) @ xdt            # (Q, p)
 
@@ -67,7 +75,8 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, final_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
+             interpret: Optional[bool] = None):
     """x: (b, s, h, p)  dt: (b, s, h)  A, D: (h,)  B, C: (b, s, n)
     -> (y: (b, s, h, p), final_state: (b, h, n, p)).
 
@@ -108,7 +117,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256, interpret: bool = True):
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xr, dtr, Ar, Br, Cr, Dr)
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     final = final.reshape(b, h, n, p)

@@ -1,16 +1,20 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (architecture x input-shape x mesh)
 combination lowers AND compiles for the production meshes, and extract the
 roofline terms (FLOPs / bytes / collective bytes) from the compiled module.
 
-MUST be run as its own process (the XLA_FLAGS line above must execute
-before jax initializes devices):
+The production meshes are 512 virtual CPU devices. MUST be run as its own
+process: the environment lines below must execute before jax initializes
+devices. The CPU platform is claimed explicitly, so neither this process nor
+one it is started from ever takes an attached TPU:
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch olmo-1b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all --out experiments/dryrun
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import json
 import time

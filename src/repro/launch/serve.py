@@ -1,45 +1,32 @@
-"""Serving launcher: batched prefill + decode with a KV/SSM cache.
-
-Runs a small request loop on the available devices — demonstrates the
-serve_step path the decode dry-run shapes lower:
+"""Serving launcher: one batch of same-length requests through the serving
+engine's jitted prefill and KV/SSM-cache decode on the default device:
 
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-2.7b --reduced \
         --requests 4 --prompt-len 32 --gen 16
+
+The batch runs twice: the first call compiles, the second is timed.
 """
 from __future__ import annotations
 
 import argparse
-import time
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
-from repro.configs import ARCHS, reduced, reduced_batch
-from repro.models import registry
+from repro.configs import ARCHS, reduced
+from repro.core.rng import base_stream
+from repro.launch.compile_cache import enable_compile_cache
+from repro.serving import ServingEngine
 
 
 def serve(cfg, *, n_requests: int, prompt_len: int, gen: int, seed: int = 0):
-    params = registry.init(jax.random.key(seed), cfg)
-    batch = reduced_batch(cfg, n_requests, prompt_len, seed=seed)
-    max_seq = prompt_len + gen
-
-    t0 = time.perf_counter()
-    logits, cache = registry.prefill(params, cfg, batch, max_seq=max_seq)
-    t_prefill = time.perf_counter() - t0
-
-    decode = jax.jit(
-        lambda p, c, pos, tok: registry.decode_step(p, cfg, c, pos, tok))
-    tok = jnp.argmax(logits[:, -1:, :cfg.vocab_size], axis=-1)
-    out = [tok]
-    t0 = time.perf_counter()
-    for t in range(gen - 1):
-        logits, cache = decode(params, cache, jnp.int32(prompt_len + t), tok)
-        tok = jnp.argmax(logits[:, :, :cfg.vocab_size], axis=-1)
-        out.append(tok)
-    jax.block_until_ready(tok)
-    t_decode = time.perf_counter() - t0
-    tokens = jnp.concatenate(out, axis=1)
-    return tokens, t_prefill, t_decode
+    """-> (tokens (n_requests, gen), prefill_s, decode_s) of the warm run."""
+    engine = ServingEngine(cfg, seed=seed)
+    prompts = base_stream(seed).randint(
+        0, cfg.vocab_size, size=(n_requests, prompt_len)).astype(np.int32)
+    batch = engine.batch_inputs(prompts)
+    engine.generate(batch, gen)
+    out = engine.generate(batch, gen)
+    return out.tokens, out.prefill_s, out.decode_s
 
 
 def main():
@@ -51,6 +38,7 @@ def main():
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = reduced(cfg)

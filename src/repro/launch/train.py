@@ -1,12 +1,10 @@
-"""Training launcher.
+"""Training launcher: real training on the available devices, with the
+batch split over the ``data`` axis of a (n_devices, 1) mesh:
 
-Two modes:
- - real training on the available devices (reduced/any config that fits):
-     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --reduced \
-         --steps 50 --batch 8 --seq 128
- - production-mesh lowering check (delegates to dryrun for one pair):
-     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --dry-run
+    PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --reduced \
+        --steps 50 --batch 8 --seq 128
 
+The production-mesh lowering check is ``python -m repro.launch.dryrun``.
 The SMLT strategy knob (--strategy hier|hier1|allreduce) selects the
 gradient-synchronization dataflow (see launch/steps.py).
 """
@@ -16,12 +14,12 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
 from repro.configs import ARCHS, reduced
 from repro.data import DataConfig, ShardedLoader, TokenDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.models import registry
 from repro.optim import AdamW, warmup_cosine
@@ -32,37 +30,45 @@ def make_local_mesh():
     return Mesh(devs.reshape(len(devs), 1), ("data", "model"))
 
 
+def init_state(cfg, opt, pshard, oshard):
+    """Params (seed 0) and optimizer state built in place, in their step
+    layouts: a sharded optimizer state never exists replicated."""
+    params = jax.jit(lambda k: registry.init(k, cfg),
+                     out_shardings=pshard)(jax.random.key(0))
+    return params, jax.jit(opt.init, out_shardings=oshard)(params)
+
+
 def train(cfg, *, steps: int, batch: int, seq: int, strategy: str,
           lr: float = 3e-4, log_every: int = 10, loader=None):
+    """-> (params, losses, step_s): ``step_s[i]`` is step i's host wall
+    time, data loading and (for the first step) compilation included."""
     mesh = make_local_mesh()
     opt = AdamW(lr=lr, schedule=warmup_cosine(max(steps // 20, 1), steps))
     step_fn, pshard, oshard, bshard_fn = make_train_step(
         cfg, mesh, strategy=strategy, optimizer=opt)
-    params = jax.device_put(registry.init(jax.random.key(0), cfg), pshard)
-    opt_state = jax.device_put(opt.init(params), oshard)
+    params, opt_state = init_state(cfg, opt, pshard, oshard)
 
     loader = loader or ShardedLoader(TokenDataset(DataConfig(
         vocab_size=cfg.vocab_size, seq_len=seq)))
-    losses = []
-    t0 = time.perf_counter()
+    losses, step_s = [], []
     for i in range(steps):
+        t0 = time.perf_counter()
         batch_np = loader.next_batch(batch)
-        b = {"tokens": jnp.asarray(batch_np["tokens"]),
-             "labels": jnp.asarray(batch_np["labels"])}
+        b = {"tokens": batch_np["tokens"], "labels": batch_np["labels"]}
         if cfg.family == "vlm":
-            b["image_embeds"] = jnp.zeros(
+            b["image_embeds"] = np.zeros(
                 (batch, cfg.n_image_tokens, cfg.d_vision), cfg.dtype)
         if cfg.family == "audio":
-            b["audio_frames"] = jnp.zeros(
+            b["audio_frames"] = np.zeros(
                 (batch, cfg.n_audio_frames, cfg.d_audio), cfg.dtype)
+        b = jax.device_put(b, bshard_fn(b))
         params, opt_state, loss = step_fn(params, opt_state, b)
         losses.append(float(loss))
+        step_s.append(time.perf_counter() - t0)
         if i % log_every == 0 or i == steps - 1:
-            dt = time.perf_counter() - t0
-            tput = (i + 1) * batch * seq / dt
-            print(f"step {i:5d}  loss {float(loss):.4f}  "
-                  f"{tput:,.0f} tok/s", flush=True)
-    return params, losses
+            print(f"step {i:5d}  loss {losses[-1]:.4f}  "
+                  f"{batch * seq / step_s[-1]:,.0f} tok/s", flush=True)
+    return params, losses, step_s
 
 
 def main():
@@ -75,20 +81,14 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--strategy", default="hier",
                     choices=["hier", "hier1", "allreduce"])
-    ap.add_argument("--dry-run", action="store_true")
     args = ap.parse_args()
 
-    if args.dry_run:
-        from repro.launch import dryrun  # noqa: F401 (sets XLA_FLAGS? no —)
-        raise SystemExit(
-            "use `python -m repro.launch.dryrun` directly: it must set "
-            "XLA_FLAGS before jax initializes")
-
+    enable_compile_cache()
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = reduced(cfg)
-    _, losses = train(cfg, steps=args.steps, batch=args.batch, seq=args.seq,
-                      strategy=args.strategy, lr=args.lr)
+    _, losses, _ = train(cfg, steps=args.steps, batch=args.batch,
+                         seq=args.seq, strategy=args.strategy, lr=args.lr)
     print(f"final loss {losses[-1]:.4f} (from {losses[0]:.4f})")
 
 

@@ -225,8 +225,7 @@ def apply_attention(params, cfg: ModelConfig, x, *, positions=None,
         out = kops.flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=True, window=window,
-            block_q=min(256, s), block_k=min(256, s),
-            interpret=jax.default_backend() != "tpu")
+            block_q=min(256, s), block_k=min(256, s))
         out = out.transpose(0, 2, 1, 3)
     else:
         out = blockwise_attention(q, k, v, causal=causal, q_offset=q_offset,

@@ -165,8 +165,7 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
         # Pallas SSD chunk-scan kernel (train/prefill-from-scratch path)
         from repro.kernels import ops as kops
         y, S = kops.ssd_scan(x, dt, A, B, C, bp["D"],
-                             chunk=min(cfg.ssm_chunk, s),
-                             interpret=jax.default_backend() != "tpu")
+                             chunk=min(cfg.ssm_chunk, s))
     else:
         y, S = ssd_chunked(x, dt, A, B, C, bp["D"], cfg.ssm_chunk,
                            initial_state=s0)

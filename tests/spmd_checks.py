@@ -70,9 +70,8 @@ def check_sync_property():
 
         specs = jax.tree.map(
             lambda _: jax.sharding.PartitionSpec("data"), tree)
-        from repro.core.hier_sync import shard_map_compat
-        out = shard_map_compat(f, mesh=mesh, in_specs=(specs,),
-                               out_specs=specs)(tree)
+        out = jax.shard_map(f, mesh=mesh, in_specs=(specs,),
+                            out_specs=specs, check_vma=False)(tree)
         for k in tree:
             want = np.broadcast_to(np.asarray(tree[k]).mean(0, keepdims=True),
                                    tree[k].shape)
