@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper figure/table + roofline + kernels.
+"""Benchmark harness: one module per paper figure/table + roofline.
 
     PYTHONPATH=src python -m benchmarks.run            # all benchmarks
     PYTHONPATH=src python -m benchmarks.run fig7 fig8  # subset
@@ -22,9 +22,9 @@ import time
 from benchmarks.common import emit_json
 from benchmarks import (async_staleness, backend_arbitrage, comm_breakdown,
                         comm_scaling, comm_strategies, config_sensitivity,
-                        dynamic_batching, hetero_fleet, kernels_bench,
-                        multi_job, nas_adaptation, online_learning,
-                        optimizer_compare, overlap_pipeline, roofline,
+                        dynamic_batching, hetero_fleet, multi_job,
+                        nas_adaptation, online_learning, optimizer_compare,
+                        overlap_pipeline, roofline,
                         scenarios, serving_contention, serving_slo,
                         shard_ablation, straggler_tail, workflow_hpo)
 
@@ -48,7 +48,6 @@ BENCHES = {
     "event_multi_job": multi_job,
     "workflow_hpo": workflow_hpo,
     "backend_arbitrage": backend_arbitrage,
-    "kernels": kernels_bench,
     "roofline": roofline,
 }
 

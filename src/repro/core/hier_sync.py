@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
+
 STRATEGIES = ("allreduce", "hier", "hier2", "hier2_q", "ps")
 
 
@@ -93,6 +95,7 @@ def two_level_mean(grads, inner_axis: str, outer_axis: str, n_inner: int,
     return jax.tree.map(one, grads)
 
 
+@obs.scoped("grad_sync")
 def sync_grads(grads, strategy: str, *, data_axis: str = "data",
                pod_axis: str = "pod", n_data: int = 1, n_pod: int = 1):
     """Dispatch on strategy name (inside shard_map over the data/pod axes)."""

@@ -14,6 +14,8 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro import obs
+
 
 def _base_stream(seed: int):
     """Deferred: ``repro.core.__init__`` reaches back into this module
@@ -74,14 +76,17 @@ class ShardedLoader:
         self.state = state or IteratorState()
 
     def next_batch(self, global_batch: int) -> Dict[str, np.ndarray]:
-        s = self.state
-        toks = self.ds.sample(s.epoch, s.index, global_batch, self.ds.cfg.seq_len)
-        s.index += global_batch
-        epoch_samples = self.ds.cfg.dataset_tokens // self.ds.cfg.seq_len
-        if s.index >= epoch_samples:
-            s.epoch += 1
-            s.index = 0
-        return {"tokens": toks, "labels": toks.copy()}
+        with obs.span("smlt.data.next_batch"):
+            s = self.state
+            toks = self.ds.sample(s.epoch, s.index, global_batch,
+                                  self.ds.cfg.seq_len)
+            s.index += global_batch
+            epoch_samples = self.ds.cfg.dataset_tokens // self.ds.cfg.seq_len
+            if s.index >= epoch_samples:
+                s.epoch += 1
+                s.index = 0
+            obs.count("data.tokens", toks.size)
+            return {"tokens": toks, "labels": toks.copy()}
 
 
 class OnlineStream:

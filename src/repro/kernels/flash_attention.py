@@ -117,5 +117,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
         interpret=resolve_interpret(interpret),
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)

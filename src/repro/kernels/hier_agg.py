@@ -65,6 +65,7 @@ def aggregate_shards(shards: jax.Array, *, block: int = 8 * 1024,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((length,), shards.dtype),
         interpret=resolve_interpret(interpret),
+        name="hier_agg",
     )(shards)
 
 
@@ -84,4 +85,5 @@ def aggregate_and_apply(shards: jax.Array, param_shard: jax.Array, *,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((length,), param_shard.dtype),
         interpret=resolve_interpret(interpret),
+        name="hier_agg_apply",
     )(shards, param_shard)

@@ -118,6 +118,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=resolve_interpret(interpret),
+        name="ssd_scan",
     )(xr, dtr, Ar, Br, Cr, Dr)
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     final = final.reshape(b, h, n, p)

@@ -26,6 +26,7 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.distributed.sharding import (batch_specs, cache_specs, named,
                                         param_specs)
 from repro.launch.mesh import axis_size, data_axes, data_size
@@ -79,11 +80,13 @@ def make_train_step(cfg: ModelConfig, mesh, *, strategy: str = "hier",
                    if gaxes else pspecs)
     ospecs = AdamWState(step=P(), mu=ospecs_base, nu=ospecs_base)
 
-    def step(params, opt_state, batch):
+    def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(
             lambda p: registry.loss_fn(p, cfg, batch))(params)
         if gaxes:
-            grads = jax.lax.with_sharding_constraint(grads, named(mesh, zspecs))
+            with jax.named_scope("grad_sync"):
+                grads = jax.lax.with_sharding_constraint(
+                    grads, named(mesh, zspecs))
         new_params, new_state = opt.update(grads, opt_state, params)
         return new_params, new_state, loss
 
@@ -92,8 +95,9 @@ def make_train_step(cfg: ModelConfig, mesh, *, strategy: str = "hier",
 
     pshard = named(mesh, pspecs)
     oshard = named(mesh, ospecs)
+    obs.build("train_step")
     jit_step = jax.jit(
-        step,
+        train_step,
         in_shardings=None,  # taken from arguments at lower time
         out_shardings=(pshard, oshard, NamedSharding(mesh, P())),
         donate_argnums=(0, 1) if donate else ())
@@ -111,13 +115,13 @@ def make_prefill_step(cfg: ModelConfig, mesh, *, fsdp: bool = False):
                          fsdp_axis=(daxes if fsdp else None),
                          fsdp_divisor=dsize)
 
-    def step(params, batch):
+    def prefill_step(params, batch):
         return registry.prefill(params, cfg, batch)
 
     def batch_shardings(batch_shapes):
         return named(mesh, batch_specs(batch_shapes, daxes, data_size=dsize))
 
-    return jax.jit(step), named(mesh, pspecs), batch_shardings
+    return jax.jit(prefill_step), named(mesh, pspecs), batch_shardings
 
 
 def make_serve_step(cfg: ModelConfig, mesh, *, fsdp: bool = False):
@@ -132,7 +136,7 @@ def make_serve_step(cfg: ModelConfig, mesh, *, fsdp: bool = False):
                          fsdp_axis=(daxes if fsdp else None),
                          fsdp_divisor=dsize)
 
-    def step(params, cache, pos, tokens):
+    def decode_step(params, cache, pos, tokens):
         return registry.decode_step(params, cfg, cache, pos, tokens)
 
     def cache_shardings(cache_shapes):
@@ -142,7 +146,8 @@ def make_serve_step(cfg: ModelConfig, mesh, *, fsdp: bool = False):
     def batch_shardings(batch_shapes):
         return named(mesh, batch_specs(batch_shapes, daxes, data_size=dsize))
 
-    return jax.jit(step), named(mesh, pspecs), cache_shardings, batch_shardings
+    return (jax.jit(decode_step), named(mesh, pspecs), cache_shardings,
+            batch_shardings)
 
 
 def decode_cache_shapes(cfg: ModelConfig, batch: int, max_seq: int,

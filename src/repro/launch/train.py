@@ -11,12 +11,12 @@ gradient-synchronization dataflow (see launch/steps.py).
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.configs import ARCHS, reduced
 from repro.data import DataConfig, ShardedLoader, TokenDataset
 from repro.launch.compile_cache import enable_compile_cache
@@ -41,7 +41,10 @@ def init_state(cfg, opt, pshard, oshard):
 def train(cfg, *, steps: int, batch: int, seq: int, strategy: str,
           lr: float = 3e-4, log_every: int = 10, loader=None):
     """-> (params, losses, step_s): ``step_s[i]`` is step i's host wall
-    time, data loading and (for the first step) compilation included."""
+    time, data loading and (for the first step) compilation included: the
+    duration of its ``smlt.train.step`` span, which encloses the loader's
+    ``smlt.data.next_batch`` and ``smlt.train.put``, ``.dispatch`` and
+    ``.loss_read``."""
     mesh = make_local_mesh()
     opt = AdamW(lr=lr, schedule=warmup_cosine(max(steps // 20, 1), steps))
     step_fn, pshard, oshard, bshard_fn = make_train_step(
@@ -52,19 +55,22 @@ def train(cfg, *, steps: int, batch: int, seq: int, strategy: str,
         vocab_size=cfg.vocab_size, seq_len=seq)))
     losses, step_s = [], []
     for i in range(steps):
-        t0 = time.perf_counter()
-        batch_np = loader.next_batch(batch)
-        b = {"tokens": batch_np["tokens"], "labels": batch_np["labels"]}
-        if cfg.family == "vlm":
-            b["image_embeds"] = np.zeros(
-                (batch, cfg.n_image_tokens, cfg.d_vision), cfg.dtype)
-        if cfg.family == "audio":
-            b["audio_frames"] = np.zeros(
-                (batch, cfg.n_audio_frames, cfg.d_audio), cfg.dtype)
-        b = jax.device_put(b, bshard_fn(b))
-        params, opt_state, loss = step_fn(params, opt_state, b)
-        losses.append(float(loss))
-        step_s.append(time.perf_counter() - t0)
+        with obs.span("smlt.train.step") as sp:
+            batch_np = loader.next_batch(batch)
+            b = {"tokens": batch_np["tokens"], "labels": batch_np["labels"]}
+            if cfg.family == "vlm":
+                b["image_embeds"] = np.zeros(
+                    (batch, cfg.n_image_tokens, cfg.d_vision), cfg.dtype)
+            if cfg.family == "audio":
+                b["audio_frames"] = np.zeros(
+                    (batch, cfg.n_audio_frames, cfg.d_audio), cfg.dtype)
+            with obs.span("smlt.train.put"):
+                b = jax.device_put(b, bshard_fn(b))
+            with obs.span("smlt.train.dispatch"):
+                params, opt_state, loss = step_fn(params, opt_state, b)
+            with obs.span("smlt.train.loss_read"):
+                losses.append(float(loss))
+        step_s.append(sp.seconds)
         if i % log_every == 0 or i == steps - 1:
             print(f"step {i:5d}  loss {losses[-1]:.4f}  "
                   f"{batch * seq / step_s[-1]:,.0f} tok/s", flush=True)

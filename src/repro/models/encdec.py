@@ -85,14 +85,8 @@ def apply_dec_block(bp, cfg: ModelConfig, h, ckv, *, positions=None,
         bp["self_attn"], cfg, L.apply_norm(bp["ln1"], cfg, h),
         positions=positions, cache=cache, cache_index=cache_index)
     h = h + a
-    b, s, _ = h.shape
-    hd = cfg.resolved_head_dim
-    x = L.apply_norm(bp["ln_x"], cfg, h)
-    q = (x @ bp["cross_attn"]["wq"]).reshape(b, s, cfg.n_heads, hd)
-    k = L._repeat_kv(ckv["k"], cfg.n_heads // cfg.n_kv_heads)
-    v = L._repeat_kv(ckv["v"], cfg.n_heads // cfg.n_kv_heads)
-    o = L.blockwise_attention(q, k, v, causal=False)
-    h = h + o.reshape(b, s, cfg.n_heads * hd) @ bp["cross_attn"]["wo"]
+    h = h + L.cross_attention(bp["cross_attn"], cfg,
+                              L.apply_norm(bp["ln_x"], cfg, h), ckv)
     h = h + L.apply_mlp(bp["mlp"], cfg, L.apply_norm(bp["ln2"], cfg, h))
     return h, new_cache
 

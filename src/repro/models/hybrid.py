@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import layers as L
 from repro.models import mamba2 as M
 from repro.models import transformer as T
@@ -44,14 +45,22 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_seq: int):
 
 def shared_attn_decode(bp, cfg: ModelConfig, h, attn_cache, pos):
     """One-token attention against a ring-buffer window cache."""
-    b = h.shape[0]
+    x = L.apply_norm(bp["ln1"], cfg, h)
+    o, new_cache = _ring_attention(bp["attn"], cfg, x, attn_cache, pos)
+    h = h + o
+    h = h + L.apply_mlp(bp["mlp"], cfg, L.apply_norm(bp["ln2"], cfg, h))
+    return h, new_cache
+
+
+@obs.scoped("attn")
+def _ring_attention(p, cfg: ModelConfig, x, attn_cache, pos):
+    b = x.shape[0]
     hd = cfg.resolved_head_dim
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
     size = attn_cache["k"].shape[1]
-    x = L.apply_norm(bp["ln1"], cfg, h)
-    q = (x @ bp["attn"]["wq"]).reshape(b, 1, nq, hd)
-    k = (x @ bp["attn"]["wk"]).reshape(b, 1, nkv, hd)
-    v = (x @ bp["attn"]["wv"]).reshape(b, 1, nkv, hd)
+    q = (x @ p["wq"]).reshape(b, 1, nq, hd)
+    k = (x @ p["wk"]).reshape(b, 1, nkv, hd)
+    v = (x @ p["wv"]).reshape(b, 1, nkv, hd)
     positions = pos + jnp.zeros((1,), jnp.int32)
     q = L.apply_rope(q, positions, cfg.rope_theta)
     k = L.apply_rope(k, positions, cfg.rope_theta)
@@ -62,15 +71,15 @@ def shared_attn_decode(bp, cfg: ModelConfig, h, attn_cache, pos):
                                       (0, slot, 0, 0))
     kk = L._repeat_kv(ck, nq // nkv).astype(jnp.float32)
     vv = L._repeat_kv(cv, nq // nkv).astype(jnp.float32)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * hd ** -0.5, kk)
-    valid = jnp.arange(size) < jnp.minimum(pos + 1, size)
-    s = jnp.where(valid[None, None, None], s, -1e30)
-    pvals = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", pvals, vv).astype(h.dtype)
-    o = o.reshape(b, 1, nq * hd) @ bp["attn"]["wo"]
-    h = h + o
-    h = h + L.apply_mlp(bp["mlp"], cfg, L.apply_norm(bp["ln2"], cfg, h))
-    return h, {"k": ck, "v": cv}
+    with jax.named_scope("core"):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * hd ** -0.5,
+                       kk)
+        valid = jnp.arange(size) < jnp.minimum(pos + 1, size)
+        s = jnp.where(valid[None, None, None], s, -1e30)
+        pvals = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", pvals, vv).astype(x.dtype)
+    o = o.reshape(b, 1, nq * hd) @ p["wo"]
+    return o, {"k": ck, "v": cv}
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int):

@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models.base import ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -46,6 +47,7 @@ def init_norm(cfg: ModelConfig, dtype=None):
     raise ValueError(f"unknown norm {cfg.norm!r}")
 
 
+@obs.scoped("norm")
 def apply_norm(params, cfg: ModelConfig, x, eps: float = 1e-5):
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
@@ -59,6 +61,7 @@ def apply_norm(params, cfg: ModelConfig, x, eps: float = 1e-5):
     return y.astype(x.dtype)
 
 
+@obs.scoped("norm")
 def rmsnorm_raw(x, scale, eps: float = 1e-5):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
@@ -123,6 +126,7 @@ def _repeat_kv(x, n_rep: int):
         b, s, kv * n_rep, d)
 
 
+@obs.scoped("core")
 def blockwise_attention(q, k, v, *, causal: bool, q_offset=0,
                         sliding_window: int = 0, q_block: int = 512):
     """Online-softmax attention, scanned over query blocks.
@@ -168,6 +172,7 @@ def blockwise_attention(q, k, v, *, causal: bool, q_offset=0,
     return out[:, :sq].astype(q.dtype)
 
 
+@obs.scoped("attn")
 def apply_attention(params, cfg: ModelConfig, x, *, positions=None,
                     causal: bool = True, cache: Optional[dict] = None,
                     cache_index=None, kv_input=None, use_rope: bool = True,
@@ -222,16 +227,30 @@ def apply_attention(params, cfg: ModelConfig, x, *, positions=None,
             and causal and s > 1):
         # Pallas flash-attention kernel (self-attention prefill/train path)
         from repro.kernels import ops as kops
-        out = kops.flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, window=window,
-            block_q=min(256, s), block_k=min(256, s))
+        with jax.named_scope("core"):
+            out = kops.flash_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), causal=True, window=window,
+                block_q=min(256, s), block_k=min(256, s))
         out = out.transpose(0, 2, 1, 3)
     else:
         out = blockwise_attention(q, k, v, causal=causal, q_offset=q_offset,
                                   sliding_window=window)
     out = out.reshape(b, s, nq * hd) @ params["wo"]
     return out, new_cache
+
+
+@obs.scoped("attn")
+def cross_attention(params, cfg: ModelConfig, x, kv):
+    """x: (b, s, d) attending, unmasked, over precomputed K/V
+    {"k", "v"}: (b, n, kv, hd) (image tokens, encoder frames)."""
+    b, s, _ = x.shape
+    hd = cfg.resolved_head_dim
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+    q = (x @ params["wq"]).reshape(b, s, cfg.n_heads, hd)
+    o = blockwise_attention(q, _repeat_kv(kv["k"], n_rep),
+                            _repeat_kv(kv["v"], n_rep), causal=False)
+    return o.reshape(b, s, cfg.n_heads * hd) @ params["wo"]
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: int, n_kv=None,
@@ -261,6 +280,7 @@ def init_mlp(rng, cfg: ModelConfig, d_ff: Optional[int] = None,
             "wo": dense_init(ks[2], d_ff, d_model, cfg.dtype)}
 
 
+@obs.scoped("mlp")
 def apply_mlp(params, cfg: ModelConfig, x):
     if "wg" in params:
         return (jax.nn.silu(x @ params["wi"]) * (x @ params["wg"])) @ params["wo"]
@@ -280,16 +300,19 @@ def init_embed(rng, cfg: ModelConfig):
     return p
 
 
+@obs.scoped("embed")
 def embed_tokens(params, x):
     return jnp.take(params["tok"], x, axis=0)
 
 
+@obs.scoped("unembed")
 def unembed(params, cfg: ModelConfig, h):
     if cfg.tie_embeddings:
         return h @ params["tok"].T
     return h @ params["unembed"]
 
 
+@obs.scoped("loss")
 def cross_entropy(logits, labels, cfg: ModelConfig):
     """Mean next-token CE; masks vocab-padding columns and label==-1."""
     vp = logits.shape[-1]

@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.base import ModelConfig
@@ -45,6 +46,7 @@ def causal_conv(x, w, state=None):
 # ---------------------------------------------------------------------------
 
 
+@obs.scoped("core")
 def ssd_chunked(x, dt, A, B, C, D, chunk: int, initial_state=None):
     """x: (b,s,h,p)  dt: (b,s,h) (post-softplus)  A: (h,) (negative)
     B, C: (b,s,n)  D: (h,). Returns (y: (b,s,h,p), final_state: (b,h,n,p))."""
@@ -101,6 +103,7 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int, initial_state=None):
     return y.astype(x.dtype), final_state
 
 
+@obs.scoped("core")
 def ssd_decode_step(S, x, dt, A, B, C, D):
     """One-token recurrence. x: (b,h,p)  dt: (b,h)  B, C: (b,n)  S: (b,h,n,p)."""
     xf, dtf = x.astype(jnp.float32), dt.astype(jnp.float32)
@@ -141,6 +144,7 @@ def init_mamba_block(rng, cfg: ModelConfig, d_model: Optional[int] = None):
     }
 
 
+@obs.scoped("ssd")
 def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
     """cache: {"conv_x", "conv_BC", "ssm"} or None. Returns (out, new_cache)."""
     b, s, d = h.shape
@@ -164,8 +168,9 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
     if cfg.use_ssd_kernel and s0 is None:
         # Pallas SSD chunk-scan kernel (train/prefill-from-scratch path)
         from repro.kernels import ops as kops
-        y, S = kops.ssd_scan(x, dt, A, B, C, bp["D"],
-                             chunk=min(cfg.ssm_chunk, s))
+        with jax.named_scope("core"):
+            y, S = kops.ssd_scan(x, dt, A, B, C, bp["D"],
+                                 chunk=min(cfg.ssm_chunk, s))
     else:
         y, S = ssd_chunked(x, dt, A, B, C, bp["D"], cfg.ssm_chunk,
                            initial_state=s0)
@@ -176,6 +181,7 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
     return h + out, new_cache
 
 
+@obs.scoped("ssd")
 def apply_mamba_decode(bp, cfg: ModelConfig, h, cache):
     """Single-token path (s == 1) using the recurrent form."""
     b, s, d = h.shape

@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.base import ModelConfig
@@ -53,43 +54,46 @@ def _moe_group(p, cfg: ModelConfig, xt):
     E, k = _n_experts_padded(cfg), cfg.top_k
     cap = max(int(cfg.moe_capacity_factor * k * g / E), 1)
 
-    logits = (xt @ p["router"]).astype(jnp.float32)          # (g, E)
-    if E > cfg.n_experts:  # padding experts are never routed to
-        pad_mask = jnp.arange(E) >= cfg.n_experts
-        logits = jnp.where(pad_mask, -1e30, logits)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)             # (g, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        logits = (xt @ p["router"]).astype(jnp.float32)          # (g, E)
+        if E > cfg.n_experts:  # padding experts are never routed to
+            pad_mask = jnp.arange(E) >= cfg.n_experts
+            logits = jnp.where(pad_mask, -1e30, logits)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)             # (g, k)
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
 
-    # position of each (token, expert-slot) within its expert's capacity
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)   # (g, k, E)
-    pos_in_expert = (jnp.cumsum(onehot.reshape(g * k, E), axis=0)
-                     .reshape(g, k, E) - onehot)
-    pos = jnp.sum(pos_in_expert * onehot, axis=-1).astype(jnp.int32)  # (g, k)
-    keep = pos < cap
-    gate_vals = gate_vals * keep
+        # position of each (token, expert-slot) within its expert's capacity
+        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)   # (g, k, E)
+        pos_in_expert = (jnp.cumsum(onehot.reshape(g * k, E), axis=0)
+                         .reshape(g, k, E) - onehot)
+        pos = jnp.sum(pos_in_expert * onehot, axis=-1).astype(jnp.int32)
+        keep = pos < cap
+        gate_vals = gate_vals * keep
 
-    # dispatch (g, E, cap) / combine tensors
-    pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) * keep[..., None]
-    dispatch = jnp.einsum("tke,tkc->tec", onehot, pos_oh)
-    combine = jnp.einsum("tke,tkc,tk->tec", onehot, pos_oh, gate_vals)
+        # dispatch (g, E, cap) / combine tensors
+        pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) * keep[..., None]
+        dispatch = jnp.einsum("tke,tkc->tec", onehot, pos_oh)
+        combine = jnp.einsum("tke,tkc,tk->tec", onehot, pos_oh, gate_vals)
 
-    ex_in = jnp.einsum("tec,td->ecd", dispatch, xt.astype(jnp.float32))
-    ex_in = ex_in.astype(xt.dtype)
-    ex = p["experts"]
-    hidden = jax.nn.silu(jnp.einsum("ecd,edf->ecf", ex_in, ex["wi"]))
-    hidden = hidden * jnp.einsum("ecd,edf->ecf", ex_in, ex["wg"])
-    ex_out = jnp.einsum("ecf,efd->ecd", hidden, ex["wo"])
-    out = jnp.einsum("tec,ecd->td", combine, ex_out.astype(jnp.float32))
+        # GShard load-balance aux loss
+        frac_tokens = jnp.mean(jnp.sum(onehot, axis=1), axis=0)   # (E,)
+        frac_probs = jnp.mean(probs, axis=0)
+        aux = E * jnp.sum(frac_tokens * frac_probs) * cfg.router_aux_weight
 
-    # GShard load-balance aux loss
-    frac_tokens = jnp.mean(jnp.sum(onehot, axis=1), axis=0)   # (E,)
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs) * cfg.router_aux_weight
+    with jax.named_scope("experts"):
+        ex_in = jnp.einsum("tec,td->ecd", dispatch, xt.astype(jnp.float32))
+        ex_in = ex_in.astype(xt.dtype)
+        ex = p["experts"]
+        hidden = jax.nn.silu(jnp.einsum("ecd,edf->ecf", ex_in, ex["wi"]))
+        hidden = hidden * jnp.einsum("ecd,edf->ecf", ex_in, ex["wg"])
+        ex_out = jnp.einsum("ecf,efd->ecd", hidden, ex["wo"])
+        out = jnp.einsum("tec,ecd->td", combine, ex_out.astype(jnp.float32))
     return out.astype(xt.dtype), aux
 
 
+@obs.scoped("moe")
 def apply_moe_ffn(p, cfg: ModelConfig, x):
     """x: (b, s, d) -> (out, aux_loss). Tokens are dispatched in GShard-style
     groups so the dispatch tensors stay (g, E, C) with g <= MOE_GROUP."""

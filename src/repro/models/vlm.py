@@ -39,14 +39,8 @@ def init_cross_block(rng, cfg: ModelConfig):
 
 def apply_cross_block(bp, cfg: ModelConfig, h, image_kv):
     """image_kv: {"k": (b, n_img, kv, hd), "v": ...} precomputed."""
-    b, s, _ = h.shape
-    hd = cfg.resolved_head_dim
-    x = L.apply_norm(bp["ln1"], cfg, h)
-    q = (x @ bp["attn"]["wq"]).reshape(b, s, cfg.n_heads, hd)
-    k = L._repeat_kv(image_kv["k"], cfg.n_heads // cfg.n_kv_heads)
-    v = L._repeat_kv(image_kv["v"], cfg.n_heads // cfg.n_kv_heads)
-    o = L.blockwise_attention(q, k, v, causal=False)
-    o = o.reshape(b, s, cfg.n_heads * hd) @ bp["attn"]["wo"]
+    o = L.cross_attention(bp["attn"], cfg, L.apply_norm(bp["ln1"], cfg, h),
+                          image_kv)
     h = h + jnp.tanh(bp["gate_attn"]) * o
     m = L.apply_mlp(bp["mlp"], cfg, L.apply_norm(bp["ln2"], cfg, h))
     return h + jnp.tanh(bp["gate_mlp"]) * m

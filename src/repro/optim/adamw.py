@@ -12,6 +12,8 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 class AdamWState(NamedTuple):
     step: jnp.ndarray
@@ -35,13 +37,16 @@ class AdamW:
                           mu=jax.tree.map(zeros, params),
                           nu=jax.tree.map(zeros, params))
 
+    @obs.scoped("optimizer")
     def update(self, grads, state: AdamWState, params):
         step = state.step + 1
         if self.grad_clip:
-            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                                 for g in jax.tree.leaves(grads)))
-            scale = jnp.minimum(1.0, self.grad_clip / (gnorm + 1e-9))
-            grads = jax.tree.map(lambda g: g * scale, grads)
+            with jax.named_scope("clip"):
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)))
+                scale = jnp.minimum(1.0, self.grad_clip / (gnorm + 1e-9))
+                grads = jax.tree.map(lambda g: g * scale, grads)
         b1, b2 = self.b1, self.b2
         mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g.astype(jnp.float32),
                           state.mu, grads)

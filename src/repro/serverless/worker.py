@@ -17,15 +17,15 @@ Two execution paths share the same interfaces:
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.rng import base_stream
-from repro.core.comm import (CommLike, CommPlan, CommSpec, build_plan,
+from repro.core.comm import (CommLike, CommPlan, build_plan,
                              overlap_iteration_time, plan_times)
 from repro.serverless.backends import BackendLike, resolve_backend
 from repro.serverless.platform import FleetSpec, fn_gflops, fn_net_gbps
@@ -333,100 +333,117 @@ class LocalWorkerPool:
             sl = jax.tree.map(
                 lambda x: x[w * (x.shape[0] // n):(w + 1) * (x.shape[0] // n)],
                 global_batch)
-            g = self._slice_grad(self._worker_params(w, params), sl)
-            flats.append(flatten_grads(g))
+            with obs.span("smlt.pool.grads"):
+                g = self._slice_grad(self._worker_params(w, params), sl)
+            with obs.span("smlt.pool.to_host"):
+                flats.append(flatten_grads(g))
             g_like = g
         return flats, g_like
 
     def step(self, params, global_batch) -> Dict:
         """global_batch: dict of arrays with leading dim divisible by n.
-        Returns the aggregated (mean) gradient pytree."""
-        if self.plan.ratio < 1.0:
-            mean_flat, g_like = self._step_compressed(params, global_batch)
-        elif self.plan.strategy == "ps":
-            mean_flat, g_like = self._step_ps(params, global_batch)
-        elif self.plan.strategy == "hier":
-            mean_flat, g_like = self._step_hier(params, global_batch)
-        else:
-            mean_flat, g_like = self._step_scatter_reduce(params,
-                                                          global_batch)
+        Returns the aggregated (mean) gradient pytree.
+
+        Spans: ``smlt.pool.grads`` (a worker's gradient, dispatched) and
+        ``smlt.pool.to_host`` (its flat host copy, which waits for it),
+        then ``smlt.pool.aggregate`` around the whole synchronization,
+        whose self time is the aggregation arithmetic: the store's puts
+        and gets inside it are ``smlt.pool.store`` spans."""
+        flats, g_like = self._worker_grads(params, global_batch)
+        with obs.span("smlt.pool.aggregate"):
+            if self.plan.ratio < 1.0:
+                mean_flat = self._step_compressed(flats)
+            elif self.plan.strategy == "ps":
+                mean_flat = self._step_ps(flats)
+            elif self.plan.strategy == "hier":
+                mean_flat = self._step_hier(flats)
+            else:
+                mean_flat = self._step_scatter_reduce(flats)
         self._iter += 1
         return unflatten_grads(mean_flat, g_like)
 
     # -- strategy numerics ---------------------------------------------------
-    def _step_scatter_reduce(self, params, global_batch):
+    def _step_scatter_reduce(self, flats):
         n = self.n
-        flats, g_like = self._worker_grads(params, global_batch)
         flat_size = len(flats[0])
         # (1) each worker shards its gradient and uploads the shards
-        for w, flat in enumerate(flats):
-            for j, s in enumerate(make_shards(flat, n)):
-                self.store.put(f"shard/{w}/{j}", s, nbytes=s.nbytes)
+        with obs.span("smlt.pool.store"):
+            for w, flat in enumerate(flats):
+                for j, s in enumerate(make_shards(flat, n)):
+                    self.store.put(f"shard/{w}/{j}", s, nbytes=s.nbytes)
         # (2) worker j aggregates shard j from all workers (mean), re-uploads
         for j in range(n):
-            stacked = np.stack([self.store.get(f"shard/{w}/{j}")
-                                for w in range(n)])
+            with obs.span("smlt.pool.store"):
+                stacked = np.stack([self.store.get(f"shard/{w}/{j}")
+                                    for w in range(n)])
             if self.use_kernel:
                 from repro.kernels import ops as kops
                 agg = np.asarray(kops.aggregate_shards(jnp.asarray(stacked)))
             else:
                 agg = stacked.mean(axis=0)
-            self.store.put(f"aggr/{j}", agg, nbytes=agg.nbytes)
+            with obs.span("smlt.pool.store"):
+                self.store.put(f"aggr/{j}", agg, nbytes=agg.nbytes)
         # (3) every worker downloads all aggregated shards -> updated model;
         # they are identical, so reconstruct once.
-        agg = [self.store.get(f"aggr/{j}") for j in range(n)]
-        return join_shards(agg, flat_size), g_like
+        with obs.span("smlt.pool.store"):
+            agg = [self.store.get(f"aggr/{j}") for j in range(n)]
+        return join_shards(agg, flat_size)
 
-    def _step_ps(self, params, global_batch):
+    def _step_ps(self, flats):
         n = self.n
-        flats, g_like = self._worker_grads(params, global_batch)
-        for w, flat in enumerate(flats):
-            self.store.put(f"grad/{w}", flat, nbytes=flat.nbytes)
+        with obs.span("smlt.pool.store"):
+            for w, flat in enumerate(flats):
+                self.store.put(f"grad/{w}", flat, nbytes=flat.nbytes)
         acc = np.zeros(len(flats[0]), np.float32)
         for w in range(n):
-            acc += self.store.get(f"grad/{w}", nbytes=flats[w].nbytes)
-        return acc / n, g_like
+            with obs.span("smlt.pool.store"):
+                g = self.store.get(f"grad/{w}", nbytes=flats[w].nbytes)
+            acc += g
+        return acc / n
 
-    def _step_hier(self, params, global_batch):
+    def _step_hier(self, flats):
         """Tree aggregation: partial sums reduce level by level through
         the store; the root's sum / n is the exact global mean."""
         n, b = self.n, max(self.plan.branching or 4, 2)
-        flats, g_like = self._worker_grads(params, global_batch)
         nbytes = flats[0].nbytes
         partials = list(flats)                   # level-0 partial sums
         lvl = 0
         while len(partials) > 1:
             lvl += 1
-            for i, p in enumerate(partials):
-                self.store.put(f"hier/{lvl}/{i}", p, nbytes=nbytes)
+            with obs.span("smlt.pool.store"):
+                for i, p in enumerate(partials):
+                    self.store.put(f"hier/{lvl}/{i}", p, nbytes=nbytes)
             nxt = []
             for g0 in range(0, len(partials), b):
-                members = range(g0, min(g0 + b, len(partials)))
-                nxt.append(sum(self.store.get(f"hier/{lvl}/{i}",
+                with obs.span("smlt.pool.store"):
+                    members = [self.store.get(f"hier/{lvl}/{i}",
                                               nbytes=nbytes)
-                               for i in members))
+                               for i in range(g0, min(g0 + b, len(partials)))]
+                nxt.append(sum(members))
             partials = nxt
         root = partials[0]
-        self.store.put("hier/root", root, nbytes=nbytes)
-        return self.store.get("hier/root", nbytes=nbytes) / n, g_like
+        with obs.span("smlt.pool.store"):
+            self.store.put("hier/root", root, nbytes=nbytes)
+            root = self.store.get("hier/root", nbytes=nbytes)
+        return root / n
 
-    def _step_compressed(self, params, global_batch):
+    def _step_compressed(self, flats):
         """Top-k + error feedback: each worker uploads only its k largest
         (corrected) entries; the aggregator sums sparse contributions.
         Wire bytes follow the plan's compressed model (value + index)."""
         from repro.core.compression import ErrorFeedback, compressed_bytes
         n, ratio = self.n, self.plan.ratio
-        flats, g_like = self._worker_grads(params, global_batch)
         size = len(flats[0])
+        nbytes = compressed_bytes(size, ratio)
         for w, flat in enumerate(flats):
             if w not in self._ef:
                 self._ef[w] = ErrorFeedback.init(size)
             idx, vals = self._ef[w].compress(flat, ratio)
-            self.store.put(f"sparse/{w}", (idx, vals),
-                           nbytes=compressed_bytes(size, ratio))
+            with obs.span("smlt.pool.store"):
+                self.store.put(f"sparse/{w}", (idx, vals), nbytes=nbytes)
         acc = np.zeros(size, np.float32)
         for w in range(n):
-            idx, vals = self.store.get(
-                f"sparse/{w}", nbytes=compressed_bytes(size, ratio))
+            with obs.span("smlt.pool.store"):
+                idx, vals = self.store.get(f"sparse/{w}", nbytes=nbytes)
             acc[idx] += vals
-        return acc / n, g_like
+        return acc / n

@@ -8,13 +8,13 @@ is batching-invariant (a request's tokens don't depend on its batchmates).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import registry
 from repro.models.base import ModelConfig
 
@@ -36,8 +36,10 @@ class Completion:
 class Generation:
     """One batch's greedy decode. ``last_logits`` are the final decode
     step's (b, 1, vocab_padded) logits; ``prefill_s`` is host wall time to
-    the first token (prefill + its argmax, blocked on), ``decode_s`` the
-    time of the remaining ``gen - 1`` decode steps."""
+    the first token (prefill + its argmax, blocked on: the
+    ``smlt.serve.prefill`` span), ``decode_s`` the time of the remaining
+    ``gen - 1`` decode steps, blocked on (the ``smlt.serve.decode`` span,
+    around one ``smlt.serve.decode_step`` per step)."""
     tokens: jax.Array
     last_logits: jax.Array
     prefill_s: float
@@ -54,12 +56,15 @@ class ServingEngine:
         self.cfg = cfg
         self.params = params if params is not None else registry.init(
             jax.random.key(seed), cfg)
-        self._prefill = jax.jit(
-            lambda p, b, max_seq: registry.prefill(p, cfg, b,
-                                                   max_seq=max_seq),
-            static_argnums=2)
-        self._decode = jax.jit(
-            lambda p, c, pos, tok: registry.decode_step(p, cfg, c, pos, tok))
+
+        def prefill_step(p, b, max_seq):
+            return registry.prefill(p, cfg, b, max_seq=max_seq)
+
+        def decode_step(p, c, pos, tok):
+            return registry.decode_step(p, cfg, c, pos, tok)
+
+        self._prefill = jax.jit(prefill_step, static_argnums=2)
+        self._decode = jax.jit(decode_step)
 
     def batch_inputs(self, prompts: np.ndarray) -> Dict[str, jax.Array]:
         """(b, s) int32 prompts -> model inputs, with the config's modality
@@ -81,20 +86,22 @@ class ServingEngine:
     def generate(self, batch, gen: int) -> Generation:
         vocab = self.cfg.vocab_size
         plen = batch["tokens"].shape[1]
-        t0 = time.perf_counter()
-        logits, cache = self.prefill(batch, plen + gen)
-        tok = jax.block_until_ready(
-            jnp.argmax(logits[:, -1:, :vocab], axis=-1))
-        t1 = time.perf_counter()
+        with obs.span("smlt.serve.prefill") as prefill:
+            logits, cache = self.prefill(batch, plen + gen)
+            tok = jax.block_until_ready(
+                jnp.argmax(logits[:, -1:, :vocab], axis=-1))
         out = [tok]
-        for t in range(gen - 1):
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.int32(plen + t), tok)
-            tok = jnp.argmax(logits[:, :, :vocab], axis=-1)
-            out.append(tok)
-        tokens = jax.block_until_ready(jnp.concatenate(out, axis=1))
-        return Generation(tokens, logits[:, -1:], t1 - t0,
-                          time.perf_counter() - t1)
+        with obs.span("smlt.serve.decode") as decode:
+            for t in range(gen - 1):
+                with obs.span("smlt.serve.decode_step"):
+                    logits, cache = self._decode(self.params, cache,
+                                                 jnp.int32(plen + t), tok)
+                    tok = jnp.argmax(logits[:, :, :vocab], axis=-1)
+                out.append(tok)
+            tokens = jax.block_until_ready(jnp.concatenate(out, axis=1))
+        obs.count("serve.tokens", tokens.size)
+        return Generation(tokens, logits[:, -1:], prefill.seconds,
+                          decode.seconds)
 
     def serve_batch(self, requests: List[Request]) -> List[Completion]:
         lengths = {len(r.prompt) for r in requests}

@@ -47,6 +47,7 @@ def test_hier_agg_compiles(one_chip):
         lambda x: ops.aggregate_shards(x, interpret=False),
         ((8, 2 ** 20), jnp.float32), one_chip=one_chip)
     assert "tpu_custom_call" in txt
+    assert "%hier_agg" in txt    # the kernel's stable name in a device trace
 
 
 def test_flash_forward_compiles_at_olmo_widths(one_chip):
@@ -56,6 +57,7 @@ def test_flash_forward_compiles_at_olmo_widths(one_chip):
                                             interpret=False),
         qkv, qkv, qkv, one_chip=one_chip)
     assert "tpu_custom_call" in txt
+    assert "%flash_attention" in txt
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
@@ -68,3 +70,4 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
         ((h,), jnp.float32), ((b, s, n), jnp.bfloat16),
         ((b, s, n), jnp.bfloat16), ((h,), jnp.float32), one_chip=one_chip)
     assert "tpu_custom_call" in txt
+    assert "%ssd_scan" in txt
