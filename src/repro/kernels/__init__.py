@@ -12,6 +12,10 @@ The platform picks the execution mode (``resolve_interpret``): compiled
 Mosaic kernels on TPU, the Pallas interpreter on CPU, and an error on any
 other backend. Every kernel keeps an explicit ``interpret`` argument so a
 test can force a TPU compile from a CPU host.
+
+Whether the model takes a kernel is decided here too, from what the code
+observes (``flash_attention_applies``): the platform and the shapes, with
+no option to turn it on or off.
 """
 from __future__ import annotations
 
@@ -32,3 +36,22 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     raise RuntimeError(
         f"Pallas kernels here target TPU (compiled) or CPU (interpreted); "
         f"the default backend is {backend!r}")
+
+
+def pallas_compiles() -> bool:
+    """Whether the default backend compiles Pallas kernels (the TPU)."""
+    return jax.default_backend() == "tpu"
+
+
+def flash_attention_applies(seq: int, *, causal: bool,
+                            self_attention: bool) -> bool:
+    """Whether attention over ``seq`` query tokens takes the Pallas flash
+    kernels: where they compile, on one device (Mosaic kernels cannot be
+    partitioned across devices automatically), for causal self-attention
+    with no cache (training and cache-free forwards), over a sequence that
+    tiles into 128-row blocks. Everything else -- decode and prefill into a
+    cache, cross-attention, the non-causal encoder, other lengths, a step
+    over several devices, any CPU run -- takes
+    ``models.layers.blockwise_attention``."""
+    return (pallas_compiles() and jax.device_count() == 1
+            and self_attention and causal and seq > 1 and seq % 128 == 0)

@@ -47,35 +47,33 @@ def aggregate_and_apply(shards, param, *, lr: float,
     return out[:L]
 
 
-def _flash_ref_bhsd(q, k, v, causal, window):
-    """Differentiable blockwise reference in (b, h, s, d) layout — used as
-    the backward of the Pallas forward (a dedicated bwd kernel is the
-    natural next step on real hardware; the vjp-of-blockwise keeps memory
-    O(block x s) rather than O(s^2))."""
-    from repro.models.layers import blockwise_attention
-    out = blockwise_attention(q.transpose(0, 2, 1, 3),
-                              k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3),
-                              causal=causal, sliding_window=window)
-    return out.transpose(0, 2, 1, 3)
+def _block(s: int) -> int:
+    """The largest of 1024, 512, 256 and 128 rows that tiles ``s`` (1024 x
+    1024 tiles were the fastest that fit VMEM on a v5e at train.olmo-1b's
+    shape); a shorter or untiled sequence takes one block of up to 1024
+    rows, padded."""
+    return next((b for b in (1024, 512, 256, 128) if s % b == 0),
+                min(1024, s))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_diff(q, k, v, causal, window, block_q, block_k, interpret):
-    return _flash_pallas(q, k, v, causal, window, block_q, block_k,
-                         interpret)
+    return _flash_diff_fwd(q, k, v, causal, window, block_q, block_k,
+                           interpret)[0]
 
 
 def _flash_diff_fwd(q, k, v, causal, window, block_q, block_k, interpret):
-    out = _flash_pallas(q, k, v, causal, window, block_q, block_k, interpret)
-    return out, (q, k, v)
+    o, lse = _flash.flash_forward(q, k, v, causal=causal, window=window,
+                                  block_q=block_q, block_k=block_k,
+                                  interpret=interpret)
+    return o, (q, k, v, o, lse)
 
 
-def _flash_diff_bwd(causal, window, block_q, block_k, interpret, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(lambda q, k, v: _flash_ref_bhsd(q, k, v, causal, window),
-                     q, k, v)
-    return vjp(g)
+def _flash_diff_bwd(causal, window, block_q, block_k, interpret, res, do):
+    q, k, v, o, lse = res
+    return _flash.flash_backward(q, k, v, o, lse, do, causal=causal,
+                                 window=window, block_q=block_q,
+                                 block_k=block_k, interpret=interpret)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -84,37 +82,28 @@ _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 256, block_k: int = 256,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
-    """(b, h, s, d) attention; pads seq to block multiples. Differentiable:
-    Pallas forward + blockwise-jnp backward via custom_vjp."""
-    return _flash_diff(q, k, v, causal, window, block_q, block_k, interpret)
-
-
-def _flash_pallas(q, k, v, causal, window, block_q, block_k, interpret):
+    """(b, h, s, d) attention through the Pallas kernels, forward and
+    backward (custom_vjp). Block sizes default to ``_block`` of each length;
+    sequences are padded to block multiples and the output sliced back."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    block_q = min(block_q, max(16, sq))
-    block_k = min(block_k, max(16, sk))
-    qp, pq = _pad_to(q, 2, block_q)
+    block_q = min(block_q or _block(sq), max(16, sq))
+    block_k = min(block_k or _block(sk), max(16, sk))
+    qp, _ = _pad_to(q, 2, block_q)
     kp, pk = _pad_to(k, 2, block_k)
     vp, _ = _pad_to(v, 2, block_k)
-    if pk:
-        # mask out padded keys via an effective causal structure: padded keys
-        # sit at positions >= sk, queries only at < sq <= padded kv end; with
-        # causal=True they're already masked for q < sk. For non-causal we
-        # must mask explicitly:
-        if not causal:
-            kp = kp.at[:, :, sk:].set(0)
-            # give padded keys -inf scores by zero v and huge negative k? use
-            # causal-free path only with window=0 and rely on value zeroing
-            # is incorrect -> instead raise:
-            raise NotImplementedError(
-                "non-causal flash with padded kv not supported; pad inputs")
-    out = _flash.flash_attention(qp, kp, vp, causal=causal, window=window,
-                                 block_q=block_q, block_k=block_k,
-                                 interpret=interpret)
-    return out[:, :, :sq]
+    if pk and not causal:
+        # padded keys would take part in every row's softmax
+        raise NotImplementedError(
+            "non-causal flash with padded kv not supported; pad inputs")
+    sqp, skp = qp.shape[2], kp.shape[2]
+    out = _flash_diff(qp.reshape(b * h, sqp, d), kp.reshape(b * h, skp, d),
+                      vp.reshape(b * h, skp, d), causal, window, block_q,
+                      block_k, interpret)
+    return out.reshape(b, h, sqp, d)[:, :, :sq]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -77,9 +77,9 @@ class ModelConfig:
     # "full" re-computes everything; "dots" saves matmul outputs
     # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     remat_policy: str = "full"
-    # Pallas kernels (TPU; interpret-mode on CPU). Self-attention prefill
-    # and the SSD chunk scan dispatch to repro.kernels when enabled.
-    use_flash_kernel: bool = False
+    # Pallas SSD chunk scan (TPU; interpret-mode on CPU) when enabled.
+    # Flash attention needs no flag: repro.kernels.flash_attention_applies
+    # picks it from the platform and the shapes.
     use_ssd_kernel: bool = False
     # Megatron-style sequence parallelism: between blocks, activations are
     # sharded over the model axis on the sequence dim (halves TP-AR bytes)

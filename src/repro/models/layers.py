@@ -4,7 +4,9 @@ Pure-functional: parameters are nested dicts of jnp arrays, every layer is
 ``init_*`` (build params) + ``apply`` function. Attention is implemented with
 a blockwise online-softmax formulation so that 32k-token prefill lowers with
 O(block x seq) live memory instead of O(seq^2) — the jnp analogue of the
-Pallas flash-attention kernel in ``repro.kernels.flash_attention``.
+Pallas flash-attention kernels in ``repro.kernels.flash_attention``, which
+causal self-attention takes where they compile
+(``repro.kernels.flash_attention_applies``).
 """
 from __future__ import annotations
 
@@ -13,7 +15,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import obs
+from repro import kernels, obs
+from repro.kernels import ops as kops
 from repro.models.base import ModelConfig
 
 # ---------------------------------------------------------------------------
@@ -135,6 +138,7 @@ def blockwise_attention(q, k, v, *, causal: bool, q_offset=0,
     position of q[0] relative to k[0] (decode: q_offset = cache length).
     Peak live memory is O(b*h*q_block*skv) rather than O(sq*skv).
     """
+    obs.count("attn.blockwise")
     b, sq, h, d = q.shape
     skv = k.shape[1]
     scale = d ** -0.5
@@ -170,6 +174,18 @@ def blockwise_attention(q, k, v, *, causal: bool, q_offset=0,
                            (qf, jnp.arange(n_blocks)))
     out = outs.transpose(1, 0, 2, 3, 4).reshape(b, n_blocks * q_block, h, d)
     return out[:, :sq].astype(q.dtype)
+
+
+@obs.scoped("core")
+def flash_attention(q, k, v, *, sliding_window: int = 0):
+    """Causal self-attention through the Pallas flash kernels, forward and
+    backward (``repro.kernels.ops.flash_attention``); same layout and
+    result as ``blockwise_attention(q, k, v, causal=True)``."""
+    obs.count("attn.flash")
+    out = kops.flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=True, window=sliding_window)
+    return out.transpose(0, 2, 1, 3)
 
 
 @obs.scoped("attn")
@@ -223,16 +239,9 @@ def apply_attention(params, cfg: ModelConfig, x, *, positions=None,
     n_rep = nq // nkv
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
-    if (cfg.use_flash_kernel and cache is None and kv_input is None
-            and causal and s > 1):
-        # Pallas flash-attention kernel (self-attention prefill/train path)
-        from repro.kernels import ops as kops
-        with jax.named_scope("core"):
-            out = kops.flash_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3), causal=True, window=window,
-                block_q=min(256, s), block_k=min(256, s))
-        out = out.transpose(0, 2, 1, 3)
+    if kernels.flash_attention_applies(
+            s, causal=causal, self_attention=cache is None and kv_input is None):
+        out = flash_attention(q, k, v, sliding_window=window)
     else:
         out = blockwise_attention(q, k, v, causal=causal, q_offset=q_offset,
                                   sliding_window=window)

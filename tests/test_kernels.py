@@ -87,6 +87,57 @@ def test_flash_matches_model_blockwise():
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+def _bhsd(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("seq,block,window", [
+    (128, 128, 0),       # one block
+    (256, 64, 0),        # several blocks, causal skipping
+    (256, 64, 48),       # several blocks, window skipping on both sides
+    (192, 64, 16)])      # window narrower than a block
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_backward_matches_blockwise_vjp(seq, block, window, dtype):
+    """The interpreted forward (output and lse residual) and backward
+    kernels (dq, dk, dv) against jax.vjp of the model's blockwise path."""
+    from repro.kernels import flash_attention as fa
+    from repro.models.layers import blockwise_attention
+    b, h, d = 1, 2, 32
+    q, k, v, do = (jnp.array(RNG.randn(b, h, seq, d), dtype)
+                   for _ in range(4))
+    got, vjp = jax.vjp(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                            window=window, block_q=block,
+                                            block_k=block), q, k, v)
+    want, vjp_ref = jax.vjp(
+        lambda q, k, v: _bhsd(blockwise_attention(
+            _bhsd(q), _bhsd(k), _bhsd(v), causal=True,
+            sliding_window=window)), q, k, v)
+    for a, r in zip((got, *vjp(do)), (want, *vjp_ref(do))):
+        assert a.dtype == r.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(r, np.float32), **_tol(dtype))
+
+    # the lse residual: each row's log-sum-exp of its visible scaled scores
+    _, lse = fa.flash_forward(q.reshape(b * h, seq, d),
+                              k.reshape(b * h, seq, d),
+                              v.reshape(b * h, seq, d), causal=True,
+                              window=window, block_q=block, block_k=block)
+    assert lse.shape == (b * h, seq, fa.LANES) and lse.dtype == jnp.float32
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * d ** -0.5
+    pos = jnp.arange(seq)
+    mask = (pos[:, None] >= pos[None, :]) & (
+        pos[:, None] - pos[None, :] < (window or seq))
+    lse_ref = jax.nn.logsumexp(jnp.where(mask, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(lse[..., 0]),
+                               np.asarray(lse_ref).reshape(b * h, seq),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(lse),
+                                  np.asarray(lse[..., :1]).repeat(fa.LANES,
+                                                                  axis=-1))
+
+
 # ---------------------------------------------------------------------------
 # ssd scan
 # ---------------------------------------------------------------------------

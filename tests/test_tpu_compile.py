@@ -8,6 +8,7 @@ never at import: only one process may load the TPU library at a time, and
 every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +59,49 @@ def test_flash_forward_compiles_at_olmo_widths(one_chip):
         qkv, qkv, qkv, one_chip=one_chip)
     assert "tpu_custom_call" in txt
     assert "%flash_attention" in txt
+
+
+def test_flash_backward_compiles_at_cell_shapes(one_chip):
+    # train.olmo-1b's attention: batch 2, 16 heads, 2048 tokens, 128 wide
+    qkv = ((2, 16, 2048, 128), jnp.float32)
+
+    def fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=True, interpret=False), q, k, v)
+        return o, vjp(do)
+
+    txt = _compiled_text(fwd_bwd, qkv, qkv, qkv, qkv, one_chip=one_chip)
+    assert "%flash_attention_dkv" in txt
+    assert "%flash_attention_dq" in txt
+
+
+def test_attention_grad_takes_the_three_kernels(one_chip, monkeypatch):
+    """A gradient of the model's attention at olmo-1b widths runs forward
+    and backward in the kernels where they compile. The CPU's own dispatch
+    (blockwise, interpreted kernels) is steered to the TPU's here."""
+    from repro import kernels, obs
+    from repro.configs import ARCHS
+    from repro.kernels import flash_attention as fa
+    from repro.models import layers
+    monkeypatch.setattr(kernels, "pallas_compiles", lambda: True)
+    monkeypatch.setattr(fa, "resolve_interpret", lambda interpret=None: False)
+    cfg = ARCHS["olmo-1b"]
+    d = cfg.d_model
+    w = ((d, d), jnp.float32)
+
+    def loss(wq, wk, wv, wo, x):
+        p = {"wq": wq, "wk": wk, "wv": wv, "wo": wo}
+        return jnp.sum(layers.apply_attention(p, cfg, x)[0])
+
+    before = obs.snapshot()["counters"].get("attn.blockwise", 0)
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                         w, w, w, w, ((2, 2048, d), jnp.float32),
+                         one_chip=one_chip)
+    for name in ("flash_attention", "flash_attention_dkv",
+                 "flash_attention_dq"):
+        assert re.search(rf"%{name}(\.\d+)? = ", txt), name
+    assert obs.snapshot()["counters"].get("attn.blockwise", 0) == before
+    assert "while" not in txt      # no blockwise scan over query blocks
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
