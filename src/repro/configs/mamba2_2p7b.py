@@ -1,6 +1,9 @@
 """mamba2-2.7b — SSD (state-space duality) [arXiv:2405.21060].
 
-64L d_model=2560, attention-free, d_ff=0, vocab=50280, ssm_state=128.
+64L d_model=2560, attention-free, d_ff=0, ssm_state=128. The vocabulary is
+the published checkpoint's (huggingface.co/state-spaces/mamba2-2.7b,
+config.json: vocab_size 50277, padded there to a multiple of 16); the
+program pads it to a multiple of 128 and the loss masks the padding.
 """
 import jax.numpy as jnp
 
@@ -15,7 +18,7 @@ CONFIG = ModelConfig(
     n_heads=0,
     n_kv_heads=0,
     d_ff=0,
-    vocab_size=50_280,
+    vocab_size=50_277,
     ssm_state=128,
     ssm_expand=2,
     ssm_headdim=64,

@@ -45,8 +45,8 @@ _RULES = [
     (r"/wdt$",             [{-1: "model"}]),          # (d, nh)
     (r"/wB$|/wC$",         [{}]),                     # small, replicated
     (r"dt_bias$|A_log$|/D$", [{-1: "model"}]),        # (nh,)
-    (r"conv_x$",           [{-1: "model"}]),          # (W, d_inner)
-    (r"conv_BC$",          [{}]),
+    (r"conv_x$|conv_x_bias$", [{-1: "model"}]),       # (W, d_inner), (d_inner,)
+    (r"conv_BC$|conv_BC_bias$", [{}]),
     (r"gate_ln/scale$",    [{-1: "model"}]),          # (d_inner,)
     (r"blocks/wo$",        [{-2: "model"}]),          # mamba out proj
     (r"vision_proj$|audio_proj$", [{}]),

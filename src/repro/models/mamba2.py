@@ -22,21 +22,25 @@ from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.base import ModelConfig
 
+# Mamba-2's dt init range (mamba_ssm Mamba2: dt_min, dt_max, dt_init_floor)
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
+
 
 # ---------------------------------------------------------------------------
 # causal depthwise conv1d
 # ---------------------------------------------------------------------------
 
 
-def causal_conv(x, w, state=None):
-    """x: (b, s, c); w: (W, c) depthwise. state: (b, W-1, c) carried inputs.
-    Returns (out, new_state)."""
+def causal_conv(x, w, bias, state=None):
+    """x: (b, s, c); w: (W, c) depthwise; bias: (c,). state: (b, W-1, c)
+    carried inputs. Returns (silu(conv + bias), new_state)."""
     W = w.shape[0]
     if state is None:
         state = jnp.zeros((x.shape[0], W - 1, x.shape[2]), x.dtype)
     xp = jnp.concatenate([state, x], axis=1)
-    out = sum(w[i] * jax.lax.dynamic_slice_in_dim(xp, i, x.shape[1], axis=1)
-              for i in range(W))
+    out = bias + sum(
+        w[i] * jax.lax.dynamic_slice_in_dim(xp, i, x.shape[1], axis=1)
+        for i in range(W))
     new_state = xp[:, -(W - 1):]
     return jax.nn.silu(out), new_state
 
@@ -121,11 +125,25 @@ def ssd_decode_step(S, x, dt, A, B, C, D):
 # ---------------------------------------------------------------------------
 
 
+def init_dt_bias(rng, nh: int, dtype):
+    """Mamba-2's dt init: dt log-uniform in [DT_MIN, DT_MAX], floored at
+    DT_FLOOR, stored as its inverse softplus so softplus(dt_bias) = dt."""
+    lo, hi = jnp.log(DT_MIN), jnp.log(DT_MAX)
+    dt = jnp.exp(jax.random.uniform(rng, (nh,), minval=lo, maxval=hi))
+    dt = jnp.maximum(dt, DT_FLOOR)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
 def init_mamba_block(rng, cfg: ModelConfig, d_model: Optional[int] = None):
     d = d_model or cfg.d_model
     di, nh, n = cfg.d_inner, cfg.ssm_nheads, cfg.ssm_state
     W = cfg.ssm_conv_width
-    ks = jax.random.split(rng, 9)
+    ks = jax.random.split(rng, 12)
+
+    def conv_bias(k, c):   # torch Conv1d's default: U(+-1/sqrt(fan_in))
+        return jax.random.uniform(k, (c,), minval=-W ** -0.5,
+                                  maxval=W ** -0.5).astype(cfg.dtype)
+
     return {
         "ln": {"scale": jnp.ones((d,), cfg.dtype)},
         "wz": L.dense_init(ks[0], d, di, cfg.dtype),
@@ -133,12 +151,14 @@ def init_mamba_block(rng, cfg: ModelConfig, d_model: Optional[int] = None):
         "wB": L.dense_init(ks[2], d, n, cfg.dtype),
         "wC": L.dense_init(ks[3], d, n, cfg.dtype),
         "wdt": L.dense_init(ks[4], d, nh, cfg.dtype),
-        "dt_bias": jnp.zeros((nh,), cfg.dtype),
+        "dt_bias": init_dt_bias(ks[9], nh, cfg.dtype),
         "A_log": jnp.log(jax.random.uniform(ks[5], (nh,), minval=1.0,
                                             maxval=16.0)).astype(cfg.dtype),
         "D": jnp.ones((nh,), cfg.dtype),
         "conv_x": (jax.random.normal(ks[6], (W, di)) * W ** -0.5).astype(cfg.dtype),
         "conv_BC": (jax.random.normal(ks[7], (W, 2 * n)) * W ** -0.5).astype(cfg.dtype),
+        "conv_x_bias": conv_bias(ks[10], di),
+        "conv_BC_bias": conv_bias(ks[11], 2 * n),
         "gate_ln": {"scale": jnp.ones((di,), cfg.dtype)},
         "wo": L.dense_init(ks[8], di, d, cfg.dtype),
     }
@@ -159,8 +179,8 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
 
     cx = cache["conv_x"] if cache is not None else None
     cbc = cache["conv_BC"] if cache is not None else None
-    x, new_cx = causal_conv(x, bp["conv_x"], cx)
-    BC, new_cbc = causal_conv(BC, bp["conv_BC"], cbc)
+    x, new_cx = causal_conv(x, bp["conv_x"], bp["conv_x_bias"], cx)
+    BC, new_cbc = causal_conv(BC, bp["conv_BC"], bp["conv_BC_bias"], cbc)
     B, C = jnp.split(BC, 2, axis=-1)
 
     x = x.reshape(b, s, nh, p)
@@ -168,10 +188,12 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
     if cfg.use_ssd_kernel and s0 is None:
         # Pallas SSD chunk-scan kernel (train/prefill-from-scratch path)
         from repro.kernels import ops as kops
+        obs.count("ssd.kernel")
         with jax.named_scope("core"):
             y, S = kops.ssd_scan(x, dt, A, B, C, bp["D"],
                                  chunk=min(cfg.ssm_chunk, s))
     else:
+        obs.count("ssd.chunked")
         y, S = ssd_chunked(x, dt, A, B, C, bp["D"], cfg.ssm_chunk,
                            initial_state=s0)
     y = y.reshape(b, s, nh * p)
@@ -194,8 +216,10 @@ def apply_mamba_decode(bp, cfg: ModelConfig, h, cache):
                          + bp["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(bp["A_log"].astype(jnp.float32))
 
-    x, new_cx = causal_conv(x, bp["conv_x"], cache["conv_x"])
-    BC, new_cbc = causal_conv(BC, bp["conv_BC"], cache["conv_BC"])
+    x, new_cx = causal_conv(x, bp["conv_x"], bp["conv_x_bias"],
+                            cache["conv_x"])
+    BC, new_cbc = causal_conv(BC, bp["conv_BC"], bp["conv_BC_bias"],
+                              cache["conv_BC"])
     B, C = jnp.split(BC, 2, axis=-1)
 
     y, S = ssd_decode_step(cache["ssm"], x[:, 0].reshape(b, nh, p),

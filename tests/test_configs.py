@@ -8,9 +8,10 @@ from repro.configs import ARCHS, input_specs, pairs, reduced, supports
 from repro.models import registry
 from repro.models.base import INPUT_SHAPES
 
-# the assigned table, verbatim from the brief
+# the assigned table, verbatim from the brief; mamba2-2.7b's vocabulary is
+# its checkpoint's config.json (50277 before padding)
 ASSIGNED = {
-    "mamba2-2.7b": dict(n_layers=64, d_model=2560, d_ff=0, vocab_size=50280,
+    "mamba2-2.7b": dict(n_layers=64, d_model=2560, d_ff=0, vocab_size=50277,
                         ssm_state=128, family="ssm"),
     "seamless-m4t-medium": dict(n_layers=12, d_model=1024, n_heads=16,
                                 n_kv_heads=16, d_ff=4096, vocab_size=256206,

@@ -119,9 +119,10 @@ def test_causal_conv_state_continuation():
     rng = np.random.RandomState(1)
     x = jnp.array(rng.randn(2, 20, 6), jnp.float32)
     w = jnp.array(rng.randn(4, 6), jnp.float32)
-    y_full, st_full = causal_conv(x, w)
-    y1, st1 = causal_conv(x[:, :11], w)
-    y2, st2 = causal_conv(x[:, 11:], w, state=st1)
+    bias = jnp.array(rng.randn(6), jnp.float32)
+    y_full, st_full = causal_conv(x, w, bias)
+    y1, st1 = causal_conv(x[:, :11], w, bias)
+    y2, st2 = causal_conv(x[:, 11:], w, bias, state=st1)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(st2), np.asarray(st_full),

@@ -115,3 +115,40 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
         ((b, s, n), jnp.bfloat16), ((h,), jnp.float32), one_chip=one_chip)
     assert "tpu_custom_call" in txt
     assert "%ssd_scan" in txt
+
+
+def test_mamba2_cell_train_step_fits_one_chip(one_chip):
+    """train.mamba2-2.7b's step as the benchmark builds it (12 of 64
+    layers, float32 parameters and AdamW state, remat, 2 x 2048 tokens)
+    compiles for one v5e and its arguments and temporaries fit the chip's
+    16 GiB."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.configs import ARCHS
+    from repro.launch.steps import make_train_step
+    from repro.models import registry
+    from repro.optim import AdamW
+    cfg = ARCHS["mamba2-2.7b"].replace(n_layers=12, dtype=jnp.float32,
+                                       remat=True)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    opt = AdamW()
+    step, pshard, oshard, bshard = make_train_step(cfg, mesh, strategy="hier",
+                                                   optimizer=opt)
+    pshapes = jax.eval_shape(lambda k: registry.init(k, cfg),
+                             jax.random.key(0))
+    oshapes = jax.eval_shape(opt.init, pshapes)
+    tok = jax.ShapeDtypeStruct((2, 2048), jnp.int32)
+    batch = {"tokens": tok, "labels": tok}
+
+    def placed(shapes, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), shapes, shardings)
+
+    compiled = step.lower(placed(pshapes, pshard), placed(oshapes, oshard),
+                          placed(batch, bshard(batch))).compile()
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < 16 * 2 ** 30, (mem.argument_size_in_bytes,
+                                  mem.temp_size_in_bytes)
