@@ -1,8 +1,9 @@
 """Mamba2 / SSD (state-space duality) language model [arXiv:2405.21060].
 
 The SSD forward pass is the chunked "dual" form: intra-chunk work is a masked
-attention-like matmul (quadratic in the chunk length only), inter-chunk work
-is a linear recurrence over per-chunk states, scanned with ``lax.scan``.
+attention-like matmul (quadratic in the chunk length only), computed for all
+chunks at once, as is each chunk's own state; only the linear recurrence over
+the per-chunk states is scanned with ``lax.scan``.
 Decode is the O(1)-per-token recurrent form — this is why mamba2 runs the
 ``long_500k`` shape that quadratic-attention models cannot.
 
@@ -53,7 +54,14 @@ def causal_conv(x, w, bias, state=None):
 @obs.scoped("core")
 def ssd_chunked(x, dt, A, B, C, D, chunk: int, initial_state=None):
     """x: (b,s,h,p)  dt: (b,s,h) (post-softplus)  A: (h,) (negative)
-    B, C: (b,s,n)  D: (h,). Returns (y: (b,s,h,p), final_state: (b,h,n,p))."""
+    B, C: (b,s,n)  D: (h,). Returns (y: (b,s,h,p), final_state: (b,h,n,p)).
+
+    Every chunk's intra-chunk output and state are batched einsums over all
+    chunks at once; only the recurrence over the (b,h,n,p) chunk states is
+    a ``lax.scan``. Per-head tensors keep the chunk axis minor,
+    (b,nc,h,p,Q): a chunk of x is one transpose of its (Q, h*p) block, so
+    splitting h*p into heads never leaves p (64) as the minor axis, which
+    would pad to 128 lanes on a TPU."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     pad = (-s) % chunk
@@ -64,46 +72,51 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int, initial_state=None):
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
     nc = x.shape[1] // chunk
 
-    def to_chunks(t):
-        return t.reshape((b, nc, chunk) + t.shape[2:]).swapaxes(0, 1)
+    def to_chunks(t):                           # (b,s,...) -> (b,nc,Q,...)
+        return t.astype(jnp.float32).reshape((b, nc, chunk) + t.shape[2:])
 
-    xc, dtc, Bc, Cc = map(to_chunks, (x.astype(jnp.float32),
-                                      dt.astype(jnp.float32),
-                                      B.astype(jnp.float32),
-                                      C.astype(jnp.float32)))
+    xt = to_chunks(x).reshape(b, nc, chunk, h * p).swapaxes(2, 3)
+    xt = xt.reshape(b, nc, h, p, chunk)                       # (b,nc,h,p,Q)
+    dtt = to_chunks(dt).swapaxes(2, 3)                        # (b,nc,h,Q)
+    Bc, Cc = to_chunks(B), to_chunks(C)                       # (b,nc,Q,n)
     Af = A.astype(jnp.float32)
 
     if initial_state is None:
         initial_state = jnp.zeros((b, h, n, p), jnp.float32)
 
+    seg = jnp.cumsum(dtt * Af[:, None], axis=-1)              # (b,nc,h,Q)
+    seg_last = seg[..., -1:]                                  # (b,nc,h,1)
+    xdt = xt * dtt[:, :, :, None]                             # (b,nc,h,p,Q)
+
+    # diagonal blocks: C B^T is shared by the heads (one group). Mask the
+    # exponent BEFORE exp: for i<j, seg_i - seg_j > 0 overflows.
     idx = jnp.arange(chunk)
     causal = idx[:, None] >= idx[None, :]
+    CB = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)                # (b,nc,Q,Q)
+    diff = jnp.where(causal, seg[..., :, None] - seg[..., None, :],
+                     -jnp.inf)                                # (b,nc,h,Q,Q)
+    scores = CB[:, :, None] * jnp.exp(diff)
+    y = jnp.einsum("bchij,bchpj->bchpi", scores, xdt)
 
-    def chunk_body(S, xs):
-        x_c, dt_c, B_c, C_c = xs               # (b,Q,h,p) (b,Q,h) (b,Q,n)
-        dA = dt_c * Af                          # (b,Q,h)
-        seg = jnp.cumsum(dA, axis=1)            # (b,Q,h)
-        xdt = x_c * dt_c[..., None]
-        # intra-chunk: attention-like masked matmul
-        CB = jnp.einsum("bin,bjn->bij", C_c, B_c)
-        # mask the exponent BEFORE exp: for i<j, seg_i - seg_j > 0 overflows
-        diff = jnp.where(causal[None, :, :, None],
-                         seg[:, :, None, :] - seg[:, None, :, :], -jnp.inf)
-        scores = CB[..., None] * jnp.exp(diff)                    # (b,Q,Q,h)
-        y = jnp.einsum("bijh,bjhp->bihp", scores, xdt)
-        # inter-chunk: contribution of the carried state
-        y = y + jnp.einsum("bin,bhnp->bihp", C_c, S) * jnp.exp(seg)[..., None]
-        # state update
-        seg_last = seg[:, -1, :]                # (b,h)
-        Bx = jnp.einsum("bjn,bjhp->bhnp",
-                        B_c, xdt * jnp.exp(seg_last[:, None] - seg)[..., None])
-        S = S * jnp.exp(seg_last)[:, :, None, None] + Bx
-        return S, y
+    # each chunk's own state, decayed to the chunk's end
+    states = jnp.einsum("bcjn,bchpj->cbhnp", Bc,
+                        xdt * jnp.exp(seg_last - seg)[:, :, :, None])
 
-    final_state, yc = jax.lax.scan(chunk_body, initial_state,
-                                   (xc, dtc, Bc, Cc))
-    y = yc.swapaxes(0, 1).reshape(b, nc * chunk, h, p)[:, :s]
-    y = y + D.astype(jnp.float32)[None, None, :, None] * x[:, :s].astype(jnp.float32)
+    # state passing, the only sequential part: S_c = a_c S_{c-1} + states_c
+    def pass_state(S, inp):
+        a, st = inp                             # (b,h) (b,h,n,p)
+        return S * a[..., None, None] + st, S
+
+    chunk_decay = jnp.exp(seg_last[..., 0]).transpose(1, 0, 2)   # (nc,b,h)
+    final_state, S_in = jax.lax.scan(pass_state, initial_state,
+                                     (chunk_decay, states))
+
+    # off-diagonal blocks: the state each chunk starts from
+    y = y + (jnp.einsum("bcin,cbhnp->bchpi", Cc, S_in)
+             * jnp.exp(seg)[:, :, :, None])
+    y = y + D.astype(jnp.float32)[:, None, None] * xt
+    y = y.reshape(b, nc, h * p, chunk).swapaxes(2, 3)
+    y = y.reshape(b, nc * chunk, h, p)[:, :s]
     return y.astype(x.dtype), final_state
 
 

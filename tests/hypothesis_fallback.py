@@ -1,11 +1,12 @@
 """Degraded stand-in for the optional ``hypothesis`` dependency.
 
 The property tests only use ``@given`` with ``st.integers`` /
-``st.sampled_from`` strategies. When hypothesis is not installed, this
-module provides the same decorator surface but materializes a fixed,
-seeded set of example cases instead of doing adaptive search — the
-properties are still exercised (including range endpoints), just without
-shrinking or example databases.
+``st.sampled_from`` strategies, and ``@example`` for explicit cases.
+When hypothesis is not installed, this module provides the same
+decorator surface but materializes a fixed, seeded set of example cases
+instead of doing adaptive search — the properties are still exercised
+(including range endpoints), just without shrinking or example
+databases.
 
 Usage in a test module::
 
@@ -73,6 +74,15 @@ def settings(max_examples=DEFAULT_MAX_EXAMPLES, **_ignored):
     return deco
 
 
+def example(**kwargs):
+    """Records one explicit case for ``given``, run before the drawn ones."""
+    def deco(fn):
+        fn._fallback_examples = [kwargs] + getattr(fn, "_fallback_examples",
+                                                   [])
+        return fn
+    return deco
+
+
 def given(**strategies):
     def deco(fn):
         @functools.wraps(fn)
@@ -85,6 +95,8 @@ def given(**strategies):
             rng = np.random.RandomState(0)
             columns = {name: s.examples(rng, n)
                        for name, s in strategies.items()}
+            for case in getattr(fn, "_fallback_examples", []):
+                fn(*args, **kwargs, **case)
             for i in range(n):
                 fn(*args, **kwargs, **{k: v[i] for k, v in columns.items()})
 

@@ -19,8 +19,10 @@ HLO = """
 
 
 def test_collective_parser_counts_and_bytes():
-    from repro.launch import dryrun  # safe: only sets XLA_FLAGS env string
-    stats = dryrun.collective_stats(HLO)
+    # hlo_stats, not dryrun: importing dryrun sets XLA_FLAGS to 512 host
+    # devices, which every later test in this worker process would get
+    from repro.launch.hlo_stats import collective_stats
+    stats = collective_stats(HLO)
     assert stats["all-gather"]["count"] == 2          # ag + ag-start
     assert stats["all-reduce"]["count"] == 1
     assert stats["reduce-scatter"]["count"] == 1

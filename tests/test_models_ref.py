@@ -4,10 +4,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 except ImportError:                      # optional dep: fixed example cases
-    from hypothesis_fallback import given, settings, st
+    from hypothesis_fallback import example, given, settings, st
 
 from repro.kernels import ref
 from repro.models import layers as L
@@ -33,44 +33,90 @@ def test_blockwise_attention_property(seq, qb, window):
                                rtol=2e-4, atol=2e-5)
 
 
-@given(s=st.integers(8, 80), chunk=st.sampled_from([8, 16, 32]))
-@settings(max_examples=15, deadline=None)
-def test_ssd_chunked_equals_sequential(s, chunk):
-    rng = np.random.RandomState(s * 13 + chunk)
-    b, h, p, n = 1, 2, 8, 4
+def _ssd_inputs(rng, b, s, h, p, n, dt_floor=0.0):
     x = jnp.array(rng.randn(b, s, h, p), jnp.float32)
-    dt = jnp.array(np.abs(rng.randn(b, s, h)) * 0.4 + 0.01, jnp.float32)
+    dt = jnp.array(np.abs(rng.randn(b, s, h)) * 0.4 + dt_floor, jnp.float32)
     A = -jnp.array(np.abs(rng.randn(h)) + 0.3, jnp.float32)
     B = jnp.array(rng.randn(b, s, n), jnp.float32)
     C = jnp.array(rng.randn(b, s, n), jnp.float32)
+    return x, dt, A, B, C
+
+
+def _ssd_grads(fns, rng, args, y_shape, S_shape):
+    """Gradients of each ``fn``'s (y, final_state) with respect to all six
+    inputs, through one scalar with the same random weights on both
+    outputs: a full vector-Jacobian product."""
+    wy = jnp.array(rng.randn(*y_shape), jnp.float32)
+    wS = jnp.array(rng.randn(*S_shape), jnp.float32)
+
+    def grads(f):
+        def loss(*a):
+            y, S = f(*a)
+            return jnp.sum(y * wy) + jnp.sum(S * wS)
+        return jax.jit(jax.grad(loss, argnums=tuple(range(6))))(*args)
+    return [grads(f) for f in fns]
+
+
+def _assert_grads_close(got, want, rtol, atol):
+    for name, g, w in zip(("x", "dt", "A", "B", "C", "D"), got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=rtol,
+                                   atol=atol * float(jnp.max(jnp.abs(w))),
+                                   err_msg=f"d/d{name}")
+
+
+@given(s=st.integers(8, 80), chunk=st.sampled_from([8, 16, 32]))
+@example(s=45, chunk=16)                 # a padded last chunk
+@settings(max_examples=15, deadline=None)
+def test_ssd_chunked_equals_sequential(s, chunk):
+    """Outputs, final state and the gradient with respect to every input
+    match the per-token recurrence."""
+    rng = np.random.RandomState(s * 13 + chunk)
+    b, h, p, n = 1, 2, 8, 4
+    x, dt, A, B, C = _ssd_inputs(rng, b, s, h, p, n, dt_floor=0.01)
     D = jnp.array(rng.randn(h), jnp.float32)
-    y, S = ssd_chunked(x, dt, A, B, C, D, chunk)
+    y, S = jax.jit(ssd_chunked, static_argnums=6)(x, dt, A, B, C, D, chunk)
     yr, Sr = ref.ref_ssd(x, dt, A, B, C, D)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(S), np.asarray(Sr),
                                rtol=3e-4, atol=3e-4)
 
+    got, want = _ssd_grads(
+        [lambda *a: ssd_chunked(*a, chunk), ref.ref_ssd], rng,
+        (x, dt, A, B, C, D), y.shape, S.shape)
+    _assert_grads_close(got, want, rtol=1e-4, atol=1e-5)
+
 
 def test_ssd_state_continuation():
-    """Splitting a sequence and carrying the state == processing it whole."""
+    """Splitting a sequence and carrying the state == processing it whole,
+    in the outputs and in the gradients, which flow back through the
+    carried state (``initial_state``) into the first half."""
     rng = np.random.RandomState(0)
     b, s, h, p, n = 1, 64, 2, 8, 4
-    x = jnp.array(rng.randn(b, s, h, p), jnp.float32)
-    dt = jnp.array(np.abs(rng.randn(b, s, h)) * 0.4, jnp.float32)
-    A = -jnp.array(np.abs(rng.randn(h)) + 0.3, jnp.float32)
-    B = jnp.array(rng.randn(b, s, n), jnp.float32)
-    C = jnp.array(rng.randn(b, s, n), jnp.float32)
+    x, dt, A, B, C = _ssd_inputs(rng, b, s, h, p, n)
     D = jnp.zeros(h, jnp.float32)
-    y_full, S_full = ssd_chunked(x, dt, A, B, C, D, 16)
     h1 = 32
-    y1, S1 = ssd_chunked(x[:, :h1], dt[:, :h1], A, B[:, :h1], C[:, :h1], D, 16)
-    y2, S2 = ssd_chunked(x[:, h1:], dt[:, h1:], A, B[:, h1:], C[:, h1:], D, 16,
-                         initial_state=S1)
-    np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
+
+    def whole(x, dt, A, B, C, D):
+        return ssd_chunked(x, dt, A, B, C, D, 16)
+
+    def halves(x, dt, A, B, C, D):
+        y1, S1 = ssd_chunked(x[:, :h1], dt[:, :h1], A, B[:, :h1], C[:, :h1],
+                             D, 16)
+        y2, S2 = ssd_chunked(x[:, h1:], dt[:, h1:], A, B[:, h1:], C[:, h1:],
+                             D, 16, initial_state=S1)
+        return jnp.concatenate([y1, y2], 1), S2
+
+    y_full, S_full = jax.jit(whole)(x, dt, A, B, C, D)
+    y_split, S2 = jax.jit(halves)(x, dt, A, B, C, D)
+    np.testing.assert_allclose(np.asarray(y_split),
                                np.asarray(y_full), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(S2), np.asarray(S_full),
                                rtol=1e-4, atol=1e-4)
+
+    got, want = _ssd_grads([halves, whole], rng, (x, dt, A, B, C, D),
+                           y_full.shape, S_full.shape)
+    _assert_grads_close(got, want, rtol=1e-4, atol=1e-5)
 
 
 def test_cross_entropy_ignores_vocab_padding():

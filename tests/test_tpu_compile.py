@@ -152,3 +152,47 @@ def test_mamba2_cell_train_step_fits_one_chip(one_chip):
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < 16 * 2 ** 30, (mem.argument_size_in_bytes,
                                   mem.temp_size_in_bytes)
+
+
+def _loops(jaxpr):
+    """Every ``scan`` and ``while`` equation in a jaxpr, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scan", "while"):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _loops(sub)
+
+
+def test_ssd_chunked_grad_loops_only_over_states():
+    """The gradient of ``ssd_chunked`` at train.mamba2-2.7b's widths loops
+    only over the (b, h, n, p) chunk states and their (b, h) decays: no
+    loop carries, slices or closes over a chunk-length block of x, dt, B
+    or C. The intra-chunk work is batched over all chunks. Only the jaxpr
+    is built: nothing compiles."""
+    from repro.models.mamba2 import ssd_chunked
+    b, s, h, p, n, chunk = 2, 2048, 80, 64, 128, 256
+    state = (b, h, n, p)
+
+    def state_or_decay(sh):       # a decay may keep unit axes to broadcast
+        return sh == state or (sh[:2] == (b, h) and set(sh[2:]) <= {1})
+
+    def loss(x, dt, A, B, C, D):
+        y, S = ssd_chunked(x, dt, A, B, C, D, chunk)
+        return jnp.sum(y) + jnp.sum(S)
+
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(sh, f32) for sh in
+              ((b, s, h, p), (b, s, h), (h,), (b, s, n), (b, s, n), (h,))]
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(6))))(*shapes)
+    loops = list(_loops(jaxpr.jaxpr))
+    assert loops, "the state recurrence is a scan"
+    for eqn in loops:
+        assert eqn.primitive.name == "scan", eqn.primitive.name
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        consts = [v.aval.shape for v in eqn.invars[:nc]]
+        carry = [v.aval.shape for v in eqn.invars[nc:nc + nk]]
+        xs = [v.aval.shape[1:] for v in eqn.invars[nc + nk:]]
+        ys = [v.aval.shape[1:] for v in eqn.outvars[nk:]]
+        assert all(sh == state for sh in carry), carry
+        assert all(map(state_or_decay, xs + ys)), (xs, ys)
+        assert all(chunk not in sh and s not in sh for sh in consts), consts
