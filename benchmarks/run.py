@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper figure/table + roofline.
+"""Benchmark harness: one module per paper figure/table.
 
     PYTHONPATH=src python -m benchmarks.run            # all benchmarks
     PYTHONPATH=src python -m benchmarks.run fig7 fig8  # subset
@@ -9,9 +9,8 @@
 module still imports, runs, and emits rows, cheap enough for CI.
 
 Prints a ``name,us_per_call,derived`` CSV line per benchmark (us_per_call is
-the harness wall time for that benchmark; `derived` is its headline result)
-followed by the §Roofline table. Detailed rows go to
-experiments/bench/<name>.json.
+the harness wall time for that benchmark; `derived` is its headline
+result). Detailed rows go to experiments/bench/<name>.json.
 """
 from __future__ import annotations
 
@@ -24,9 +23,9 @@ from benchmarks import (async_staleness, backend_arbitrage, comm_breakdown,
                         comm_scaling, comm_strategies, config_sensitivity,
                         dynamic_batching, hetero_fleet, multi_job,
                         nas_adaptation, online_learning, optimizer_compare,
-                        overlap_pipeline, roofline,
-                        scenarios, serving_contention, serving_slo,
-                        shard_ablation, straggler_tail, workflow_hpo)
+                        overlap_pipeline, scenarios, serving_contention,
+                        serving_slo, shard_ablation, straggler_tail,
+                        workflow_hpo)
 
 BENCHES = {
     "fig1_2_8_comm_scaling": comm_scaling,
@@ -48,7 +47,6 @@ BENCHES = {
     "event_multi_job": multi_job,
     "workflow_hpo": workflow_hpo,
     "backend_arbitrage": backend_arbitrage,
-    "roofline": roofline,
 }
 
 # the CI smoke set: the event-path benchmarks (cheap, no BO search inside)
@@ -76,7 +74,6 @@ def main() -> None:
     which = [a for a in args if not a.startswith("--")]
     which = which or (QUICK if quick else list(BENCHES))
     print("name,us_per_call,derived")
-    roofline_rows = None
     for name in which:
         mod = BENCHES[[k for k in BENCHES if name in k][0]] \
             if name not in BENCHES else BENCHES[name]
@@ -86,11 +83,6 @@ def main() -> None:
         derived = mod.summarize(rows) if hasattr(mod, "summarize") else ""
         print(f"{name},{us:.0f},\"{derived}\"", flush=True)
         emit_json(name, rows)
-        if mod is roofline:
-            roofline_rows = rows
-    if roofline_rows is not None:
-        print()
-        print(roofline.table(roofline_rows))
 
 
 if __name__ == "__main__":

@@ -3,7 +3,6 @@
  - hier_agg:        sharded gradient mean-aggregation + fused SGD apply
                     (the paper's shard-aggregator hot loop)
  - flash_attention: online-softmax causal/sliding-window attention
- - ssd_scan:        Mamba2 chunked SSD scan with VMEM-resident state
 
 ``ops`` holds the jit'd padded wrappers (differentiable where training
 needs it); ``ref`` the independent pure-jnp oracles.

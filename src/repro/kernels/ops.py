@@ -11,7 +11,6 @@ import jax.numpy as jnp
 
 from repro.kernels import hier_agg as _hier
 from repro.kernels import flash_attention as _flash
-from repro.kernels import ssd_scan as _ssd
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -104,18 +103,3 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       vp.reshape(b * h, skp, d), causal, window, block_q,
                       block_k, interpret)
     return out.reshape(b, h, sqp, d)[:, :, :sq]
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
-             interpret: Optional[bool] = None):
-    """Mamba2 SSD over (b, s, h, p); pads seq to the chunk multiple."""
-    b, s, h, p = x.shape
-    chunk = min(chunk, max(16, s))
-    xp, pad = _pad_to(x, 1, chunk)
-    dtp, _ = _pad_to(dt, 1, chunk)
-    Bp, _ = _pad_to(B, 1, chunk)
-    Cp, _ = _pad_to(C, 1, chunk)
-    y, final = _ssd.ssd_scan(xp, dtp, A, Bp, Cp, D, chunk=chunk,
-                             interpret=interpret)
-    return y[:, :s], final

@@ -1,6 +1,7 @@
 """Multi-pod dry-run: prove every (architecture x input-shape x mesh)
-combination lowers AND compiles for the production meshes, and extract the
-roofline terms (FLOPs / bytes / collective bytes) from the compiled module.
+combination lowers AND compiles for the production meshes, and record what
+the compiler reports of the compiled module (FLOPs, bytes accessed, memory,
+collective op counts and bytes).
 
 The production meshes are 512 virtual CPU devices. MUST be run as its own
 process: the environment lines below must execute before jax initializes
@@ -54,26 +55,11 @@ def lower_one(arch_id: str, shape_name: str, *, multi_pod: bool,
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.perf_counter()
-    # mesh context: bare-PartitionSpec constraints (sequence parallelism)
-    # resolve against it; reset to the empty mesh afterwards
-    ctx = jax.set_mesh(mesh)
-    ctx.__enter__()
-    try:
-        return _lower_inner(cfg, shape, mesh, arch_id, shape_name,
-                            multi_pod, strategy, fsdp, remat, mesh_shape,
-                            overrides, t0)
-    finally:
-        ctx.__exit__(None, None, None)
-
-
-def _lower_inner(cfg, shape, mesh, arch_id, shape_name, multi_pod, strategy,
-                 fsdp, remat, mesh_shape, overrides, t0):
     if shape.kind == "train":
         cfg = cfg.replace(remat=remat)
         opt = AdamW(lr=3e-4)
         step, pshard, oshard, bshard_fn = make_train_step(
-            cfg, mesh, strategy=strategy, fsdp=fsdp, optimizer=opt,
-            donate=True)
+            cfg, mesh, strategy=strategy, fsdp=fsdp, optimizer=opt)
         pshapes = jax.eval_shape(
             lambda k: registry.init(k, cfg), jax.random.key(0))
         oshapes = jax.eval_shape(opt.init, pshapes)
@@ -153,7 +139,7 @@ def main():
     ap.add_argument("--mesh-shape", default=None,
                     help="custom mesh, e.g. 64x4 or 2x32x8 (§Perf)")
     ap.add_argument("--set", action="append", default=[],
-                    help="ModelConfig override, e.g. --set moe_group=1024")
+                    help="ModelConfig override, e.g. --set n_layers=2")
     ap.add_argument("--tag", default="", help="suffix for the output JSON")
     args = ap.parse_args()
 

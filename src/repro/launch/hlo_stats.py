@@ -1,5 +1,7 @@
-"""HLO-text collective statistics (no jax imports, no env side effects —
-safe to import from tests; repro.launch.dryrun re-exports these)."""
+"""HLO-text collective statistics: count and result bytes of each
+collective op in a compiled module's text. No jax imports and no env side
+effects, so tests import it without ``repro.launch.dryrun``'s 512-device
+set-up; the dry-run records these per lowered pair."""
 from __future__ import annotations
 
 import re
@@ -35,9 +37,8 @@ def collective_stats(hlo_text: str) -> Dict[str, Dict[str, float]]:
 
     NOTE result-size is a proxy: for ring all-reduce the wire traffic is
     ~2x the result, for all-gather ~1x, for reduce-scatter the result is
-    1/n of the input. The analytic model (benchmarks/flops_model.py)
-    applies proper ring factors; these stats are for op-mix inspection and
-    before/after comparison of the same program.
+    1/n of the input. These stats are for op-mix inspection and
+    before/after comparison of the same program, not wire bytes.
     """
     out: Dict[str, Dict[str, float]] = {}
     for m in _COLL_RE.finditer(hlo_text):

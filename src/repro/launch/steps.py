@@ -46,11 +46,11 @@ def _grad_axes(mesh, strategy: str):
 
 
 def make_train_step(cfg: ModelConfig, mesh, *, strategy: str = "hier",
-                    fsdp: bool = False, optimizer: Optional[AdamW] = None,
-                    donate: bool = True):
+                    fsdp: bool = False, optimizer: Optional[AdamW] = None):
     """-> (jitted step, params_shardings, opt_shardings, batch_shardings).
 
-    step(params, opt_state, batch) -> (params, opt_state, loss)
+    step(params, opt_state, batch) -> (params, opt_state, loss); the step
+    donates params and opt_state, so the caller's copies are consumed.
     """
     opt = optimizer or AdamW(lr=3e-4)
     model_n = axis_size(mesh, "model")
@@ -100,7 +100,7 @@ def make_train_step(cfg: ModelConfig, mesh, *, strategy: str = "hier",
         train_step,
         in_shardings=None,  # taken from arguments at lower time
         out_shardings=(pshard, oshard, NamedSharding(mesh, P())),
-        donate_argnums=(0, 1) if donate else ())
+        donate_argnums=(0, 1))
     return jit_step, pshard, oshard, batch_shardings
 
 

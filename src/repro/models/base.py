@@ -48,9 +48,6 @@ class ModelConfig:
     moe_dense_residual: bool = False  # Arctic: dense FFN residual in parallel
     router_aux_weight: float = 0.01
     moe_capacity_factor: float = 1.25  # expert capacity = cf * k * T / E
-    moe_group: int = 4096       # GShard dispatch group (perf knob, §Perf)
-    moe_pad_experts: int = 0    # pad E up (e.g. 60->64) so the expert axis
-                                # shards over the model mesh axis (§Perf)
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0          # 0 -> no ssm
@@ -74,16 +71,6 @@ class ModelConfig:
     # numerics / performance knobs
     dtype: Any = jnp.float32
     remat: bool = False
-    # "full" re-computes everything; "dots" saves matmul outputs
-    # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    remat_policy: str = "full"
-    # Pallas SSD chunk scan (TPU; interpret-mode on CPU) when enabled.
-    # Flash attention needs no flag: repro.kernels.flash_attention_applies
-    # picks it from the platform and the shapes.
-    use_ssd_kernel: bool = False
-    # Megatron-style sequence parallelism: between blocks, activations are
-    # sharded over the model axis on the sequence dim (halves TP-AR bytes)
-    seq_shard: bool = False
 
     # -- derived -------------------------------------------------------------
     @property
@@ -112,7 +99,7 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
-    # parameter-count estimate used by the cost model / roofline (dense math)
+    # parameters in the initialised tree, every expert counted
     def param_count(self) -> int:
         from repro.models import registry  # local import to avoid cycles
         return registry.param_count(self)

@@ -54,7 +54,6 @@ def encode(params, cfg: ModelConfig, audio_frames):
     h = audio_frames @ params["audio_proj"]
 
     def body(h, bp):
-        h = T.seq_constraint(cfg, h)
         a, _ = L.apply_attention(bp["attn"], cfg,
                                  L.apply_norm(bp["ln1"], cfg, h), causal=False)
         h = h + a
@@ -97,7 +96,6 @@ def decode_stack(params, cfg: ModelConfig, tokens, ckv, *, positions=None,
 
     def body(h, xs):
         bp, kv, c = xs
-        h = T.seq_constraint(cfg, h) if cache is None else h
         h, nc = apply_dec_block(bp, cfg, h, kv, positions=positions,
                                 cache=c, cache_index=cache_index)
         return h, nc

@@ -116,7 +116,6 @@ def forward_full(params, cfg: ModelConfig, tokens, *, mamba_cache=None,
 
     def group_body(h, xs):
         gbp, gc = xs
-        h = T.seq_constraint(cfg, h)
         h, ncs = jax.lax.scan(inner, h, (gbp, gc))
         b, s, _ = h.shape
         x_in = L.apply_norm(params["shared"]["ln1"], cfg, h)

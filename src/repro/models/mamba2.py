@@ -7,9 +7,9 @@ the per-chunk states is scanned with ``lax.scan``.
 Decode is the O(1)-per-token recurrent form — this is why mamba2 runs the
 ``long_500k`` shape that quadratic-attention models cannot.
 
-``repro.kernels.ssd_scan`` provides the Pallas TPU kernel for the chunk body;
-this module is the pure-jnp reference implementation used on CPU and as the
-kernel oracle.
+``ssd_chunked`` is the one SSD path for training and prefill, on every
+platform; ``repro.kernels.ref.ref_ssd``, the per-token recurrence, is its
+oracle in tests.
 """
 from __future__ import annotations
 
@@ -198,17 +198,9 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
 
     x = x.reshape(b, s, nh, p)
     s0 = cache["ssm"] if cache is not None else None
-    if cfg.use_ssd_kernel and s0 is None:
-        # Pallas SSD chunk-scan kernel (train/prefill-from-scratch path)
-        from repro.kernels import ops as kops
-        obs.count("ssd.kernel")
-        with jax.named_scope("core"):
-            y, S = kops.ssd_scan(x, dt, A, B, C, bp["D"],
-                                 chunk=min(cfg.ssm_chunk, s))
-    else:
-        obs.count("ssd.chunked")
-        y, S = ssd_chunked(x, dt, A, B, C, bp["D"], cfg.ssm_chunk,
-                           initial_state=s0)
+    obs.count("ssd.chunked")
+    y, S = ssd_chunked(x, dt, A, B, C, bp["D"], cfg.ssm_chunk,
+                       initial_state=s0)
     y = y.reshape(b, s, nh * p)
     y = L.rmsnorm_raw(y * jax.nn.silu(z), bp["gate_ln"]["scale"])
     out = y @ bp["wo"]
@@ -273,8 +265,6 @@ def forward(params, cfg: ModelConfig, tokens, *, cache=None, decode=False):
 
     def body(h, xs):
         bp, c = xs
-        if not decode:
-            h = T.seq_constraint(cfg, h)
         if decode:
             h, nc = apply_mamba_decode(bp, cfg, h, c)
         else:

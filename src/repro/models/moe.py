@@ -21,7 +21,7 @@ from repro.models.base import ModelConfig
 
 def init_moe_ffn(rng, cfg: ModelConfig):
     ks = jax.random.split(rng, 4)
-    E = _n_experts_padded(cfg)
+    E = cfg.n_experts
     d, f = cfg.d_model, cfg.d_ff
 
     def one_expert(k):
@@ -39,26 +39,21 @@ def init_moe_ffn(rng, cfg: ModelConfig):
     return p
 
 
-MOE_GROUP = 4096  # default GShard-style dispatch group (cfg.moe_group):
-                  # keeps the one-hot dispatch/combine einsums O(t * g)
-                  # instead of O(t^2)
-
-
-def _n_experts_padded(cfg: ModelConfig) -> int:
-    return max(cfg.n_experts, cfg.moe_pad_experts)
+MOE_GROUP = 4096  # GShard-style dispatch group: keeps the one-hot
+                  # dispatch/combine einsums O(t * g) instead of O(t^2).
+                  # 4096 is train_4k's sequence length, so it divides the
+                  # token count of every train and prefill shape and no
+                  # shape falls back to one whole-batch group
 
 
 def _moe_group(p, cfg: ModelConfig, xt):
     """Dispatch one token group. xt: (g, d) -> (out (g, d), aux scalar)."""
     g, d = xt.shape
-    E, k = _n_experts_padded(cfg), cfg.top_k
+    E, k = cfg.n_experts, cfg.top_k
     cap = max(int(cfg.moe_capacity_factor * k * g / E), 1)
 
     with jax.named_scope("router"):
         logits = (xt @ p["router"]).astype(jnp.float32)          # (g, E)
-        if E > cfg.n_experts:  # padding experts are never routed to
-            pad_mask = jnp.arange(E) >= cfg.n_experts
-            logits = jnp.where(pad_mask, -1e30, logits)
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, k)             # (g, k)
         gate_vals = gate_vals / jnp.maximum(
@@ -100,8 +95,7 @@ def apply_moe_ffn(p, cfg: ModelConfig, x):
     b, s, d = x.shape
     t = b * s
     xt = x.reshape(t, d)
-    group = cfg.moe_group or MOE_GROUP
-    g = group if t % group == 0 else t
+    g = MOE_GROUP if t % MOE_GROUP == 0 else t
     xg = xt.reshape(t // g, g, d)
     out, aux = jax.vmap(lambda xx: _moe_group(p, cfg, xx))(xg)
     out = out.reshape(b, s, d)
@@ -148,7 +142,6 @@ def forward(params, cfg: ModelConfig, tokens, *, positions=None, cache=None,
     def body(carry, xs):
         h, aux = carry
         bp, c = xs
-        h = T.seq_constraint(cfg, h) if cache is None else h
         h, nc, a = apply_block(bp, cfg, h, positions=positions, cache=c,
                                cache_index=cache_index)
         return (h, aux + a), nc

@@ -11,30 +11,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from jax.sharding import PartitionSpec as P
-
 from repro.models import layers as L
 from repro.models.base import ModelConfig
 
 
-def seq_constraint(cfg: ModelConfig, h):
-    """Megatron-style sequence parallelism: between blocks the activations
-    live sharded over the model axis along the sequence dim. GSPMD then
-    lowers the two per-block TP all-reduces into reduce-scatter/all-gather
-    pairs — half the bytes on the wire (§Perf)."""
-    if not cfg.seq_shard:
-        return h
-    return jax.lax.with_sharding_constraint(h, P(None, "model", None))
-
-
 def remat_wrap(cfg: ModelConfig, body):
-    if not cfg.remat:
-        return body
-    if cfg.remat_policy == "dots":
-        return jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    return jax.checkpoint(body)
+    return jax.checkpoint(body) if cfg.remat else body
 
 
 def stack_init(fn, rng, n: int):
@@ -77,7 +59,6 @@ def _scan_blocks(params, cfg: ModelConfig, h, *, positions=None, cache=None,
 
     def body(h, xs):
         bp, c = xs
-        h = seq_constraint(cfg, h)
         h, nc = apply_block(bp, cfg, h, positions=positions, cache=c,
                             cache_index=cache_index)
         return h, nc

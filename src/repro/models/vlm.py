@@ -93,7 +93,6 @@ def forward(params, cfg: ModelConfig, tokens, image_kv, *, positions=None,
 
     def group_body(h, xs):
         gbp, cbp, gc, ikv = xs
-        h = T.seq_constraint(cfg, h) if self_cache is None else h
         h, ncs = jax.lax.scan(inner, h, (gbp, gc))
         h = apply_cross_block(cbp, cfg, h, ikv)
         return h, ncs

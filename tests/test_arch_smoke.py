@@ -1,9 +1,12 @@
 """Per-architecture smoke tests: a REDUCED variant of each assigned family
 (2 layers, d_model<=512, <=4 experts) runs one forward/train step and one
-prefill+decode step on CPU; output shapes + finiteness asserted."""
+prefill+decode step on CPU; output shapes + finiteness asserted; remat
+changes no family's loss or gradients."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from test_scopes import ARCH
 
 from repro.configs import ARCHS, reduced, reduced_batch
 from repro.models import registry
@@ -71,3 +74,21 @@ def test_decode_matches_prefill(arch):
         got = logits[:, 0]
         assert jnp.allclose(ref, got, rtol=2e-2, atol=2e-3), (
             cfg.arch_id, t, float(jnp.max(jnp.abs(ref - got))))
+
+
+@pytest.mark.parametrize("family", sorted(ARCH))
+def test_remat_keeps_loss_and_grads(family):
+    """``remat=True`` recomputes each layer body in the backward pass
+    (``jax.checkpoint``) and gives the loss and gradients of the plain
+    scan."""
+    cfg = reduced(ARCHS[ARCH[family]])
+    params = registry.init(jax.random.key(0), cfg)
+    batch = reduced_batch(cfg, B, S)
+    (l0, g0), (l1, g1) = (
+        jax.jit(jax.value_and_grad(
+            lambda p, c=c: registry.loss_fn(p, c, batch)))(params)
+        for c in (cfg.replace(remat=False), cfg.replace(remat=True)))
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)

@@ -1,12 +1,17 @@
 """Config/registry coverage: assigned dims are exact, input specs build
-for every (arch x shape) pair, reduced variants respect the smoke bounds."""
+and the step traces for every (arch x shape) pair at published widths,
+reduced variants respect the smoke bounds."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from repro.configs import ARCHS, input_specs, pairs, reduced, supports
+from repro.launch import steps
 from repro.models import registry
 from repro.models.base import INPUT_SHAPES
+from repro.optim import AdamW
 
 # the assigned table, verbatim from the brief; mamba2-2.7b's vocabulary is
 # its checkpoint's config.json (50277 before padding)
@@ -77,6 +82,50 @@ def test_input_specs_build(arch_id, shape_name):
                                                    cfg.d_vision)
     if cfg.family == "audio":
         assert specs["audio_frames"].shape[2] == cfg.d_audio
+
+
+def _with(shapes, shardings):
+    return jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, shardings)
+
+
+@pytest.mark.parametrize("arch_id,shape_name", list(pairs()))
+def test_step_traces_at_published_widths(arch_id, shape_name):
+    """The pair's train, prefill or serve step traces through its step
+    builder on a one-device mesh at published widths (shapes only)."""
+    cfg = ARCHS[arch_id]
+    shape = INPUT_SHAPES[shape_name]
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+    pshapes = jax.eval_shape(lambda k: registry.init(k, cfg),
+                             jax.random.key(0))
+    specs = input_specs(cfg, shape)
+    if shape.kind == "train":
+        step, pshard, oshard, bshard = steps.make_train_step(cfg, mesh)
+        params = _with(pshapes, pshard)
+        ostate = jax.eval_shape(AdamW(lr=3e-4).init, pshapes)
+        new_params, _, loss = jax.eval_shape(
+            step, params, _with(ostate, oshard), _with(specs, bshard(specs)))
+        assert loss.shape == ()
+        assert (jax.tree.map(lambda x: (x.shape, x.dtype), new_params)
+                == jax.tree.map(lambda x: (x.shape, x.dtype), pshapes))
+        return
+    if shape.kind == "prefill":
+        step, pshard, bshard = steps.make_prefill_step(cfg, mesh)
+        logits, _ = jax.eval_shape(step, _with(pshapes, pshard),
+                                   _with(specs, bshard(specs)))
+    else:
+        step, pshard, cshard, bshard = steps.make_serve_step(cfg, mesh)
+        extras = {k: v for k, v in specs.items() if k not in ("tokens", "pos")}
+        cshapes = steps.decode_cache_shapes(cfg, shape.global_batch,
+                                            shape.seq_len,
+                                            extras_shapes=extras or None)
+        tokens = {"tokens": specs["tokens"]}
+        logits, _ = jax.eval_shape(step, _with(pshapes, pshard),
+                                   _with(cshapes, cshard(cshapes)),
+                                   specs["pos"],
+                                   _with(tokens, bshard(tokens))["tokens"])
+    assert logits.shape[0] == shape.global_batch
 
 
 @pytest.mark.parametrize("arch_id", sorted(ARCHS))

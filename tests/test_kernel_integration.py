@@ -58,29 +58,16 @@ def test_flash_kernel_grads_match(flash_forced):
     _assert_grads_close(grad(params), flash_forced(lambda: grad(params)))
 
 
-def test_ssd_kernel_path_matches():
-    cfg = reduced(ARCHS["mamba2-2.7b"])
-    params = registry.init(jax.random.key(0), cfg)
-    batch = reduced_batch(cfg, 2, 48)
-    base = registry.loss_fn(params, cfg, batch)
-    kern = registry.loss_fn(params, cfg.replace(use_ssd_kernel=True), batch)
-    np.testing.assert_allclose(float(base), float(kern), rtol=1e-4)
-
-
 def test_hybrid_window_kernel_matches(flash_forced):
     """Sliding-window flash path == windowed blockwise in the hybrid:
-    zamba2's window (32 here) skips whole tiles. The loss with the SSD
-    kernel on too; gradients with flash alone (the SSD kernel has no
-    backward)."""
+    zamba2's window (32 here) skips whole tiles. Loss and gradients."""
     cfg = reduced(ARCHS["zamba2-7b"])
     params = registry.init(jax.random.key(0), cfg)
     batch = reduced_batch(cfg, 1, 384)       # three 128-row blocks
     loss = jax.value_and_grad(lambda p: registry.loss_fn(p, cfg, batch))
     base, g0 = loss(params)
-    kcfg = cfg.replace(use_ssd_kernel=True)
-    both = flash_forced(lambda: registry.loss_fn(params, kcfg, batch))
-    np.testing.assert_allclose(float(base), float(both), rtol=1e-4)
-    _, g1 = flash_forced(lambda: loss(params))
+    flash, g1 = flash_forced(lambda: loss(params))
+    np.testing.assert_allclose(float(base), float(flash), rtol=1e-4)
     _assert_grads_close(g0, g1)
 
 

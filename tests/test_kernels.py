@@ -136,44 +136,3 @@ def test_flash_backward_matches_blockwise_vjp(seq, block, window, dtype):
     np.testing.assert_array_equal(np.asarray(lse),
                                   np.asarray(lse[..., :1]).repeat(fa.LANES,
                                                                   axis=-1))
-
-
-# ---------------------------------------------------------------------------
-# ssd scan
-# ---------------------------------------------------------------------------
-
-
-def _ssd_inputs(b, s, h, p, n, dtype=jnp.float32):
-    x = jnp.array(RNG.randn(b, s, h, p), dtype)
-    dt = jnp.array(np.abs(RNG.randn(b, s, h)) * 0.5 + 0.01, dtype)
-    A = -jnp.array(np.abs(RNG.randn(h)) + 0.5, jnp.float32)
-    B = jnp.array(RNG.randn(b, s, n), dtype)
-    C = jnp.array(RNG.randn(b, s, n), dtype)
-    D = jnp.array(RNG.randn(h), jnp.float32)
-    return x, dt, A, B, C, D
-
-
-@pytest.mark.parametrize("s,chunk", [(64, 16), (100, 32), (256, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ssd_scan(s, chunk, dtype):
-    x, dt, A, B, C, D = _ssd_inputs(2, s, 4, 16, 8, dtype)
-    y, S = ops.ssd_scan(x, dt, A, B, C, D, chunk=chunk)
-    yr, Sr = ref.ref_ssd(x, dt, A, B, C, D)
-    tol = dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 \
-        else dict(rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(y, np.float32),
-                               np.asarray(yr, np.float32), **tol)
-    np.testing.assert_allclose(np.asarray(S), np.asarray(Sr),
-                               rtol=1e-2 if dtype == jnp.bfloat16 else 2e-4,
-                               atol=1e-2 if dtype == jnp.bfloat16 else 2e-4)
-
-
-def test_ssd_kernel_matches_model_chunked():
-    from repro.models.mamba2 import ssd_chunked
-    x, dt, A, B, C, D = _ssd_inputs(1, 96, 2, 8, 4)
-    y, S = ops.ssd_scan(x, dt, A, B, C, D, chunk=32)
-    y2, S2 = ssd_chunked(x, dt, A, B, C, D, 32)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y2),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(S), np.asarray(S2),
-                               rtol=1e-4, atol=1e-4)

@@ -3,6 +3,7 @@ forms against naive references, vocab-padding handling, rope invariants."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 try:
     from hypothesis import example, given, settings
     from hypothesis import strategies as st
@@ -85,6 +86,30 @@ def test_ssd_chunked_equals_sequential(s, chunk):
         [lambda *a: ssd_chunked(*a, chunk), ref.ref_ssd], rng,
         (x, dt, A, B, C, D), y.shape, S.shape)
     _assert_grads_close(got, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("s,chunk", [(64, 16), (100, 32), (256, 64)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ssd_chunked_matches_sequential_by_dtype(s, chunk, dtype):
+    """Outputs and final state against the per-token recurrence with
+    float32 and bfloat16 inputs, including a padded last chunk (100)."""
+    rng = np.random.RandomState(0)
+    b, h, p, n = 2, 4, 16, 8
+    x = jnp.array(rng.randn(b, s, h, p), dtype)
+    dt = jnp.array(np.abs(rng.randn(b, s, h)) * 0.5 + 0.01, dtype)
+    A = -jnp.array(np.abs(rng.randn(h)) + 0.5, jnp.float32)
+    B = jnp.array(rng.randn(b, s, n), dtype)
+    C = jnp.array(rng.randn(b, s, n), dtype)
+    D = jnp.array(rng.randn(h), jnp.float32)
+    y, S = jax.jit(ssd_chunked, static_argnums=6)(x, dt, A, B, C, D, chunk)
+    yr, Sr = ref.ref_ssd(x, dt, A, B, C, D)
+    bf16 = dtype == jnp.bfloat16
+    tol = 5e-2 if bf16 else 2e-4
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(yr, np.float32), rtol=tol, atol=tol)
+    tol = 1e-2 if bf16 else 2e-4
+    np.testing.assert_allclose(np.asarray(S), np.asarray(Sr), rtol=tol,
+                               atol=tol)
 
 
 def test_ssd_state_continuation():

@@ -113,23 +113,19 @@ def test_sync_grads_scope(mesh):
     assert "/grad_sync/" in lowered.as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("kernel", [False, True])
-def test_ssd_counters_count_each_traced_layer_body(kernel):
-    """``ssd.chunked`` and ``ssd.kernel`` count, at trace time, the SSD
-    core calls a build traces, by the path taken: the layer scan's body is
-    traced once, so a loss over all layers traces one call."""
+def test_ssd_counters_count_each_traced_layer_body():
+    """``ssd.chunked`` counts, at trace time, the SSD core calls a build
+    traces: the layer scan's body is traced once, so a loss over all
+    layers traces one call."""
     from repro import obs
-    cfg = reduced(ARCHS["mamba2-2.7b"]).replace(use_ssd_kernel=kernel)
+    cfg = reduced(ARCHS["mamba2-2.7b"])
     pshapes = jax.eval_shape(lambda k: registry.init(k, cfg),
                              jax.random.key(0))
     batch = jax.eval_shape(lambda: reduced_batch(cfg))
-    taken, other = ("ssd.kernel", "ssd.chunked")[::1 if kernel else -1]
 
-    def counts():
-        c = obs.snapshot()["counters"]
-        return c.get(taken, 0), c.get(other, 0)
+    def count():
+        return obs.snapshot()["counters"].get("ssd.chunked", 0)
 
-    before = counts()
+    before = count()
     jax.make_jaxpr(lambda p, b: registry.loss_fn(p, cfg, b))(pshapes, batch)
-    after = counts()
-    assert (after[0] - before[0], after[1] - before[1]) == (1, 0)
+    assert count() - before == 1

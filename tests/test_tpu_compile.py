@@ -104,19 +104,6 @@ def test_attention_grad_takes_the_three_kernels(one_chip, monkeypatch):
     assert "while" not in txt      # no blockwise scan over query blocks
 
 
-def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
-    # mamba2-2.7b: d_inner 5120 = 80 heads x 64, state 128, chunk 256
-    b, s, h, p, n = 1, 4096, 80, 64, 128
-    txt = _compiled_text(
-        lambda x, dt, A, B, C, D: ops.ssd_scan(x, dt, A, B, C, D, chunk=256,
-                                               interpret=False),
-        ((b, s, h, p), jnp.bfloat16), ((b, s, h), jnp.float32),
-        ((h,), jnp.float32), ((b, s, n), jnp.bfloat16),
-        ((b, s, n), jnp.bfloat16), ((h,), jnp.float32), one_chip=one_chip)
-    assert "tpu_custom_call" in txt
-    assert "%ssd_scan" in txt
-
-
 def test_mamba2_cell_train_step_fits_one_chip(one_chip):
     """train.mamba2-2.7b's step as the benchmark builds it (12 of 64
     layers, float32 parameters and AdamW state, remat, 2 x 2048 tokens)
