@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.kernels import hier_agg as _hier
 from repro.kernels import flash_attention as _flash
+from repro.kernels import ssd as _ssd
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -103,3 +104,69 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       vp.reshape(b * h, skp, d), causal, window, block_q,
                       block_k, interpret)
     return out.reshape(b, h, sqp, d)[:, :, :sq]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _ssd_diff(x, dt, seg, B, C, D, S0, chunk, hb, p, interpret):
+    y, S = _ssd.ssd_forward(x, dt, seg, B, C, D, S0, chunk=chunk, hb=hb,
+                            p=p, residuals=False, interpret=interpret)
+    return y, S
+
+
+def _ssd_diff_fwd(x, dt, seg, B, C, D, S0, chunk, hb, p, interpret):
+    y, S, S_in = _ssd.ssd_forward(x, dt, seg, B, C, D, S0, chunk=chunk,
+                                  hb=hb, p=p, residuals=True,
+                                  interpret=interpret)
+    return (y, S), (x, dt, seg, B, C, D, S_in)
+
+
+def _ssd_diff_bwd(chunk, hb, p, interpret, res, cts):
+    x, dt, seg, B, C, D, S_in = res
+    dy, dS = cts
+    return _ssd.ssd_backward(x, dt, seg, B, C, D, S_in, dy, dS, chunk=chunk,
+                             hb=hb, p=p, interpret=interpret)
+
+
+_ssd_diff.defvjp(_ssd_diff_fwd, _ssd_diff_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def ssd(x, dt, A, B, C, D, *, chunk: int, initial_state=None,
+        interpret: Optional[bool] = None):
+    """``models.mamba2.ssd_chunked`` through the Pallas kernels, forward and
+    backward (custom_vjp), with its arguments and results: x (b, s, h, p),
+    dt (b, s, h), A and D (h,), B and C (b, s, n); returns (y like x, final
+    state (b, h, n, p) float32). A kernel step takes the heads of one
+    ``kernels.ssd.head_block``. The sequence is padded to a multiple of
+    ``chunk`` with dt = 0, which leaves the state unchanged.
+
+    Outside the kernels: the (b, h, s) copy of dt, ``seg`` (the cumulative
+    sum of dt * A within each chunk; its gradient gives dt's and A's) and
+    the states' (b, h/hb, n, hb*p) layout."""
+    b, s, h, p = x.shape
+    n = B.shape[-1]
+    hb = _ssd.head_block(h, p)
+    if hb is None:
+        raise ValueError(f"{h} heads of {p} do not split into 128-lane "
+                         "blocks")
+    G = h // hb
+    pad = (-s) % chunk
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+    sp = s + pad
+    dt_h = dt.astype(jnp.float32).swapaxes(1, 2)                # (b, h, sp)
+    seg = jnp.cumsum((dt_h * A.astype(jnp.float32)[:, None]).reshape(
+        b, h, sp // chunk, chunk), axis=-1).reshape(b, h, sp)
+    D_lanes = jnp.repeat(D.astype(jnp.float32), p)[None]       # (1, h*p)
+    if initial_state is None:
+        S0 = jnp.zeros((b, G, n, hb * p), jnp.float32)
+    else:
+        S0 = initial_state.astype(jnp.float32).reshape(
+            b, G, hb, n, p).swapaxes(2, 3).reshape(b, G, n, hb * p)
+    y, S = _ssd_diff(x.reshape(b, sp, h * p), dt_h, seg, B, C, D_lanes, S0,
+                     chunk, hb, p, interpret)
+    S = S.reshape(b, G, n, hb, p).swapaxes(2, 3).reshape(b, h, n, p)
+    return y.reshape(b, sp, h, p)[:, :s], S

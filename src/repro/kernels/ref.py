@@ -36,9 +36,10 @@ def ref_attention(q, k, v, *, causal: bool = True, window: int = 0):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def ref_ssd(x, dt, A, B, C, D):
+def ref_ssd(x, dt, A, B, C, D, initial_state=None):
     """Sequential (per-token) SSD recurrence — the O(s) definition.
-    x: (b, s, h, p)  dt: (b, s, h)  A, D: (h,)  B, C: (b, s, n)."""
+    x: (b, s, h, p)  dt: (b, s, h)  A, D: (h,)  B, C: (b, s, n);
+    initial_state: (b, h, n, p), zeros if None."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     xf = x.astype(jnp.float32)
@@ -55,7 +56,8 @@ def ref_ssd(x, dt, A, B, C, D):
         y = jnp.einsum("bn,bhnp->bhp", Ct, S)
         return S, y
 
-    S0 = jnp.zeros((b, h, n, p), jnp.float32)
+    S0 = (jnp.zeros((b, h, n, p), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
     S, ys = jax.lax.scan(step, S0, jnp.arange(s))
     y = ys.transpose(1, 0, 2, 3) + D.astype(jnp.float32)[None, None, :, None] * xf
     return y.astype(x.dtype), S

@@ -7,9 +7,12 @@ the per-chunk states is scanned with ``lax.scan``.
 Decode is the O(1)-per-token recurrent form — this is why mamba2 runs the
 ``long_500k`` shape that quadratic-attention models cannot.
 
-``ssd_chunked`` is the one SSD path for training and prefill, on every
-platform; ``repro.kernels.ref.ref_ssd``, the per-token recurrence, is its
-oracle in tests.
+Training and prefill take the Pallas kernels of ``repro.kernels.ssd``
+where ``repro.kernels.ssd_kernel_applies`` (one TPU chip, chunks of a
+multiple of 128 rows), and ``ssd_chunked`` everywhere else: on the CPU, on
+a step over several devices and for other shapes. ``ssd_chunked`` is the
+kernels' oracle in tests; ``repro.kernels.ref.ref_ssd``, the per-token
+recurrence, is the oracle of both.
 """
 from __future__ import annotations
 
@@ -18,7 +21,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import obs
+from repro import kernels, obs
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models.base import ModelConfig
@@ -121,6 +125,14 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int, initial_state=None):
 
 
 @obs.scoped("core")
+def ssd_kernel(x, dt, A, B, C, D, chunk: int, initial_state=None):
+    """``ssd_chunked``'s contract through the Pallas kernels
+    (``repro.kernels.ops.ssd``), under the same scope."""
+    return kops.ssd(x, dt, A, B, C, D, chunk=chunk,
+                   initial_state=initial_state)
+
+
+@obs.scoped("core")
 def ssd_decode_step(S, x, dt, A, B, C, D):
     """One-token recurrence. x: (b,h,p)  dt: (b,h)  B, C: (b,n)  S: (b,h,n,p)."""
     xf, dtf = x.astype(jnp.float32), dt.astype(jnp.float32)
@@ -198,9 +210,13 @@ def apply_mamba_block(bp, cfg: ModelConfig, h, cache=None):
 
     x = x.reshape(b, s, nh, p)
     s0 = cache["ssm"] if cache is not None else None
-    obs.count("ssd.chunked")
-    y, S = ssd_chunked(x, dt, A, B, C, bp["D"], cfg.ssm_chunk,
-                       initial_state=s0)
+    if kernels.ssd_kernel_applies(cfg.ssm_chunk, nh, p):
+        obs.count("ssd.kernel")
+        core = ssd_kernel
+    else:
+        obs.count("ssd.chunked")
+        core = ssd_chunked
+    y, S = core(x, dt, A, B, C, bp["D"], cfg.ssm_chunk, initial_state=s0)
     y = y.reshape(b, s, nh * p)
     y = L.rmsnorm_raw(y * jax.nn.silu(z), bp["gate_ln"]["scale"])
     out = y @ bp["wo"]

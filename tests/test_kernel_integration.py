@@ -112,3 +112,59 @@ def test_cpu_takes_blockwise(monkeypatch):
     assert not applies(1, causal=True, self_attention=True)
     monkeypatch.setattr(jax, "device_count", lambda: 4)
     assert not applies(2048, causal=True, self_attention=True)
+
+
+def _ssd_counts():
+    c = obs.snapshot()["counters"]
+    return c.get("ssd.kernel", 0), c.get("ssd.chunked", 0)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "zamba2-7b"])
+def test_ssd_kernel_path_matches(arch, flash_forced):
+    """Loss and gradients through the SSD kernels equal ``ssd_chunked``'s:
+    chunks of 128 over 200 tokens (a padded last chunk), two head blocks
+    of 8 heads of 16; zamba2's hybrid calls the same Mamba block."""
+    cfg = reduced(ARCHS[arch]).replace(ssm_chunk=128, remat=True)
+    params = registry.init(jax.random.key(0), cfg)
+    batch = reduced_batch(cfg, 1, 200)
+    loss = jax.value_and_grad(lambda p: registry.loss_fn(p, cfg, batch))
+    base, g0 = loss(params)
+    before = _ssd_counts()
+    kern, g1 = flash_forced(lambda: loss(params))
+    assert _ssd_counts()[0] > before[0]
+    np.testing.assert_allclose(float(base), float(kern), rtol=1e-5)
+    _assert_grads_close(g0, g1)
+
+
+@pytest.mark.parametrize("chunk,kernel", [(128, True), (16, False)])
+def test_ssd_counters_read_dispatch(chunk, kernel, flash_forced):
+    """``ssd.kernel`` and ``ssd.chunked`` count, at trace time, the path a
+    build took: the kernels for chunks of 128 rows, ``ssd_chunked`` for
+    chunks that do not tile. The layer scan traces its block once."""
+    cfg = reduced(ARCHS["mamba2-2.7b"]).replace(ssm_chunk=chunk)
+    pshapes = jax.eval_shape(lambda k: registry.init(k, cfg),
+                             jax.random.key(0))
+    batch = jax.eval_shape(lambda: reduced_batch(cfg, 1, 256))
+    before = _ssd_counts()
+    flash_forced(lambda: jax.make_jaxpr(
+        lambda p, b: registry.loss_fn(p, cfg, b))(pshapes, batch))
+    k, c = (n - m for n, m in zip(_ssd_counts(), before))
+    assert (k, c) == ((1, 0) if kernel else (0, 1))
+
+
+def test_cpu_takes_ssd_chunked(monkeypatch):
+    """Without a platform that compiles Pallas, no SSD core takes the
+    kernels; where it compiles, one device with chunks of a multiple of
+    128 rows and heads that fill 128-lane blocks does."""
+    applies = kernels.ssd_kernel_applies
+    assert not applies(256, 80, 64)
+    monkeypatch.setattr(kernels, "pallas_compiles", lambda: True)
+    assert applies(256, 80, 64)           # mamba2-2.7b: 10 blocks of 8
+    assert applies(256, 112, 64)          # zamba2-7b: 14 blocks of 8
+    assert applies(128, 16, 16)
+    assert not applies(200, 80, 64)       # chunk does not tile
+    assert not applies(64, 80, 64)
+    assert not applies(256, 3, 48)        # 144 lanes: no 128-lane block
+    assert not applies(256, 12, 16)       # 8 heads do not divide 12
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    assert not applies(256, 80, 64)

@@ -10,7 +10,7 @@ try:
 except ImportError:                      # optional dep: fixed example cases
     from hypothesis_fallback import example, given, settings, st
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.models import layers as L
 from repro.models.base import ModelConfig
 from repro.models.mamba2 import ssd_chunked
@@ -44,7 +44,7 @@ def _ssd_inputs(rng, b, s, h, p, n, dt_floor=0.0):
 
 
 def _ssd_grads(fns, rng, args, y_shape, S_shape):
-    """Gradients of each ``fn``'s (y, final_state) with respect to all six
+    """Gradients of each ``fn``'s (y, final_state) with respect to all its
     inputs, through one scalar with the same random weights on both
     outputs: a full vector-Jacobian product."""
     wy = jnp.array(rng.randn(*y_shape), jnp.float32)
@@ -54,12 +54,14 @@ def _ssd_grads(fns, rng, args, y_shape, S_shape):
         def loss(*a):
             y, S = f(*a)
             return jnp.sum(y * wy) + jnp.sum(S * wS)
-        return jax.jit(jax.grad(loss, argnums=tuple(range(6))))(*args)
+        return jax.jit(jax.grad(loss, argnums=tuple(range(len(args)))))(
+            *args)
     return [grads(f) for f in fns]
 
 
 def _assert_grads_close(got, want, rtol, atol):
-    for name, g, w in zip(("x", "dt", "A", "B", "C", "D"), got, want):
+    for name, g, w in zip(("x", "dt", "A", "B", "C", "D", "initial_state"),
+                          got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=rtol,
                                    atol=atol * float(jnp.max(jnp.abs(w))),
                                    err_msg=f"d/d{name}")
@@ -142,6 +144,45 @@ def test_ssd_state_continuation():
     got, want = _ssd_grads([halves, whole], rng, (x, dt, A, B, C, D),
                            y_full.shape, S_full.shape)
     _assert_grads_close(got, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("b,s,h,p,n,chunk,init", [
+    (1, 64, 4, 32, 64, 32, False),        # state 64, whole chunks
+    (2, 64, 2, 64, 128, 32, False),       # state 128
+    (1, 45, 2, 64, 64, 16, False),        # a padded last chunk
+    (1, 48, 4, 32, 64, 16, True),         # a carried-in state
+    (2, 40, 16, 64, 128, 16, True),       # two head blocks of 8, all of it
+])
+def test_ssd_kernel_matches_chunked_and_sequential(b, s, h, p, n, chunk,
+                                                   init):
+    """The Pallas SSD kernels (interpreted) against ``ssd_chunked`` and
+    the per-token recurrence: y, the final state, and the gradient with
+    respect to every input, the carried-in state included."""
+    rng = np.random.RandomState(s * 7 + n + h)
+    x, dt, A, B, C = _ssd_inputs(rng, b, s, h, p, n, dt_floor=0.01)
+    D = jnp.array(rng.randn(h), jnp.float32)
+    S0 = jnp.array(rng.randn(b, h, n, p) if init else np.zeros((b, h, n, p)),
+                   jnp.float32)
+    args = (x, dt, A, B, C, D, S0)
+
+    def kernel(x, dt, A, B, C, D, S0):
+        return ops.ssd(x, dt, A, B, C, D, chunk=chunk, initial_state=S0,
+                       interpret=True)
+
+    def chunked(x, dt, A, B, C, D, S0):
+        return ssd_chunked(x, dt, A, B, C, D, chunk, initial_state=S0)
+
+    y, S = jax.jit(kernel)(*args)
+    for yw, Sw in (jax.jit(chunked)(*args), ref.ref_ssd(*args)):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(yw),
+                                   rtol=3e-4, atol=3e-4)
+        np.testing.assert_allclose(np.asarray(S), np.asarray(Sw),
+                                   rtol=3e-4, atol=3e-4)
+
+    got, *want = _ssd_grads([kernel, chunked, ref.ref_ssd], rng, args,
+                            y.shape, S.shape)
+    for w in want:
+        _assert_grads_close(got, w, rtol=1e-4, atol=1e-5)
 
 
 def test_cross_entropy_ignores_vocab_padding():

@@ -104,11 +104,54 @@ def test_attention_grad_takes_the_three_kernels(one_chip, monkeypatch):
     assert "while" not in txt      # no blockwise scan over query blocks
 
 
-def test_mamba2_cell_train_step_fits_one_chip(one_chip):
+def _steer_ssd_kernels(monkeypatch):
+    """Dispatch the SSD core as on one TPU chip: the CPU's own dispatch
+    (``ssd_chunked``, interpreted kernels) is steered to the kernels,
+    compiled."""
+    from repro import kernels
+    from repro.kernels import ssd
+    monkeypatch.setattr(kernels, "pallas_compiles", lambda: True)
+    monkeypatch.setattr(ssd, "resolve_interpret", lambda interpret=None: False)
+
+
+def _ssd_counts():
+    from repro import obs
+    c = obs.snapshot()["counters"]
+    return c.get("ssd.kernel", 0), c.get("ssd.chunked", 0)
+
+
+def test_mamba2_block_grad_takes_the_ssd_kernels(one_chip, monkeypatch):
+    """The gradient of a Mamba-2 block at train.mamba2-2.7b's widths runs
+    its SSD core forward and backward in the kernels, and no (..., 256,
+    256) float32 buffer -- a chunk's Q x Q decay or scores -- reaches HBM."""
+    from repro.configs import ARCHS
+    from repro.models import mamba2
+    _steer_ssd_kernels(monkeypatch)
+    cfg = ARCHS["mamba2-2.7b"].replace(dtype=jnp.float32)
+    bp = jax.eval_shape(lambda k: mamba2.init_mamba_block(k, cfg),
+                        jax.random.key(0))
+    bp, h = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (bp, jax.ShapeDtypeStruct((2, 2048, cfg.d_model), jnp.float32)))
+
+    def loss(bp, h):
+        return jnp.sum(mamba2.apply_mamba_block(bp, cfg, h)[0])
+
+    before = _ssd_counts()
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        bp, h).compile().as_text()
+    for name in ("ssd_chunk_fwd", "ssd_chunk_bwd"):
+        assert re.search(rf"%{name}(\.\d+)? = ", txt), name
+    assert not re.search(r"f32\[[\d,]*256,256\]", txt)
+    kernel, chunked = (n - m for n, m in zip(_ssd_counts(), before))
+    assert kernel >= 1 and chunked == 0
+
+
+def test_mamba2_cell_train_step_fits_one_chip(one_chip, monkeypatch):
     """train.mamba2-2.7b's step as the benchmark builds it (12 of 64
     layers, float32 parameters and AdamW state, remat, 2 x 2048 tokens)
-    compiles for one v5e and its arguments and temporaries fit the chip's
-    16 GiB."""
+    compiles for one v5e, with the SSD kernels as one chip dispatches
+    them, and its arguments and temporaries fit the chip's 16 GiB."""
     import numpy as np
     from jax.sharding import Mesh
 
@@ -116,6 +159,7 @@ def test_mamba2_cell_train_step_fits_one_chip(one_chip):
     from repro.launch.steps import make_train_step
     from repro.models import registry
     from repro.optim import AdamW
+    _steer_ssd_kernels(monkeypatch)
     cfg = ARCHS["mamba2-2.7b"].replace(n_layers=12, dtype=jnp.float32,
                                        remat=True)
     mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
@@ -133,8 +177,11 @@ def test_mamba2_cell_train_step_fits_one_chip(one_chip):
         return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=sh), shapes, shardings)
 
+    before = _ssd_counts()
     compiled = step.lower(placed(pshapes, pshard), placed(oshapes, oshard),
                           placed(batch, bshard(batch))).compile()
+    assert [n - m for n, m in zip(_ssd_counts(), before)] == [1, 0]
+    assert "%ssd_chunk_bwd" in compiled.as_text()
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < 16 * 2 ** 30, (mem.argument_size_in_bytes,
